@@ -1061,6 +1061,8 @@ BENCHES = {
 
 
 def main() -> None:
+    from repro.launch.runtime import use_compile_cache
+    use_compile_cache()
     which = sys.argv[1:] or list(BENCHES)
     print("name,us_per_call,derived")
     for name in which:

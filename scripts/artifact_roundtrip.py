@@ -2,8 +2,10 @@
 
 Catches export/runtime drift that unit tests mock away: the *serialized*
 prefill + decode graphs must load in the model-code-free runtime and drive
-``repro.api.ArtifactBackend`` to the same event sequence as the legacy
-full-graph client loop under injected uniforms.
+``repro.api.ArtifactBackend``.  The decode-graph path and the legacy
+full-graph client loop are compiled separately, so each is held to the
+float32 reference logits under the injected uniforms
+(``repro.core.parity``); the decode-graph stream must equal its generate.
 
 Run:  PYTHONPATH=src python scripts/artifact_roundtrip.py
 """
@@ -16,13 +18,12 @@ import numpy as np
 from repro.api import Client
 from repro.configs import get_config
 from repro.core import init_delphi
+from repro.core.parity import check_trajectory, logit_tolerance
 from repro.sdk import InferenceSession, export_model, verify_checksums
 
 
 def main() -> int:
-    # same constants as the tests/test_api.py parity fixture: on untrained
-    # models the high-frequency age encoding amplifies fp fusion noise once
-    # ages drift, so a known-stable seed keeps the 6-event horizon bit-exact
+    # same constants as the tests/test_api.py parity fixture
     cfg = get_config("delphi-2m", reduced=True).replace(
         dtype="float32", vocab_size=96, max_seq_len=48)
     params = init_delphi(cfg, jax.random.PRNGKey(7))
@@ -41,14 +42,18 @@ def main() -> int:
                           uniforms=u, max_age=1e9)
     legacy = InferenceSession(d).generate_trajectory(
         toks, ages, max_new=max_new, uniforms=u, max_age=1e9)
-    assert res.tokens == legacy["tokens"], \
-        f"decode-path tokens {res.tokens} != full-graph {legacy['tokens']}"
-    assert len(res.tokens) > 0
+    for r_toks, r_ages in ((res.tokens, res.ages),
+                           (legacy["tokens"], legacy["ages"])):
+        assert len(r_toks) > 0
+        check_trajectory(params, cfg, toks, ages, r_toks, r_ages, u,
+                         tol=logit_tolerance(cfg), max_new=max_new,
+                         max_age=1e9)
     streamed = [e.token for e in client.stream(
         tokens=toks, ages=ages, max_new=max_new, uniforms=u, max_age=1e9)]
     assert streamed == res.tokens
-    print(f"OK artifact round-trip: {len(res.tokens)} events bit-identical "
-          f"across decode-graph generate/stream and the full-graph loop")
+    print(f"OK artifact round-trip: decode-graph generate/stream "
+          f"({len(res.tokens)} events) and the full-graph loop follow the "
+          f"fp32 reference")
     return 0
 
 

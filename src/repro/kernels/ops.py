@@ -1,9 +1,11 @@
 """Jit'd public wrappers around the Pallas kernels (padding, dtype policy).
 
-``interpret=True`` (the default on this CPU container) runs the kernel bodies
-through the Pallas interpreter — same code path that compiles for TPU, minus
-the Mosaic lowering.  On TPU, call with ``interpret=False`` (or set
-``ModelConfig.use_pallas=True`` so the model layers route here).
+Every wrapper takes ``interpret: Optional[bool] = None``.  ``None`` decides
+by the platform the call is lowered for (``lax.platform_dependent``): the
+Pallas interpreter on the CPU, the Mosaic kernel everywhere else.  The
+choice follows the program's target device, not ``jax.default_backend()``,
+so a program compiled for a TPU from a CPU host (a compile rehearsal) gets
+the real kernel.  ``True``/``False`` force one side.
 """
 from __future__ import annotations
 
@@ -17,6 +19,16 @@ from repro.kernels.flash_attention import flash_attention as _flash
 from repro.kernels.paged_attention import paged_decode_attention as _paged
 from repro.kernels.ssd_scan import ssd_intra as _ssd_intra
 from repro.kernels.tte_sample import tte_sample as _tte
+
+
+def _call(kernel, *args, interpret: Optional[bool], **kw):
+    """Run ``kernel(*args, interpret=..., **kw)``: interpret mode only where
+    the lowering platform is the CPU, unless the caller forces a mode."""
+    if interpret is not None:
+        return kernel(*args, interpret=interpret, **kw)
+    return jax.lax.platform_dependent(
+        *args, cpu=functools.partial(kernel, interpret=True, **kw),
+        default=functools.partial(kernel, interpret=False, **kw))
 
 
 def _pad_axis(x, axis: int, mult: int, value=0.0):
@@ -33,7 +45,8 @@ def _pad_axis(x, axis: int, mult: int, value=0.0):
                                              "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True,
                     window: Optional[int] = None, bq: int = 128,
-                    bk: int = 128, interpret: bool = True) -> jax.Array:
+                    bk: int = 128, interpret: Optional[bool] = None
+                    ) -> jax.Array:
     """q: (B, Hq, S, hd); k, v: (B, Hkv, T, hd) -> (B, Hq, S, hd).
 
     Pads S/T to block multiples; padded KV rows are masked out by the causal
@@ -47,16 +60,16 @@ def flash_attention(q, k, v, *, causal: bool = True,
     if not causal and kp.shape[2] != T:
         raise ValueError("non-causal flash requires T % bk == 0 "
                          "(padding would attend to garbage)")
-    out = _flash(qp, kp, vp, causal=causal, window=window, bq=bq, bk=bk,
-                 interpret=interpret)
+    out = _call(_flash, qp, kp, vp, causal=causal, window=window, bq=bq,
+                bk=bk, interpret=interpret)
     return out[:, :, :S]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def ssd_intra(xdt, Bm, Cm, cum, *, interpret: bool = True
+def ssd_intra(xdt, Bm, Cm, cum, *, interpret: Optional[bool] = None
               ) -> Tuple[jax.Array, jax.Array]:
     """Intra-chunk SSD: see kernels/ssd_scan.py.  Shapes (BH, C, Q, ·)."""
-    return _ssd_intra(xdt, Bm, Cm, cum, interpret=interpret)
+    return _call(_ssd_intra, xdt, Bm, Cm, cum, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("window", "interpret"))
@@ -69,16 +82,13 @@ def paged_decode_attention(q, k_pool, v_pool, table, pos, step, *,
     (NB, Hkv, bs, hd) shared block pool; table: (B, nbs) pool ids (-1 =
     unallocated); pos: (NB, bs) absolute positions (-1 = empty); step: (B,)
     per-slot query positions.  GQA by the same (Hkv, G) grouping as
-    ``decode_attention``.  ``interpret=None`` resolves by backend like
-    ``tte_sample``.  Returns (B, Hq, hd).
+    ``decode_attention``.  Returns (B, Hq, hd).
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     B, Hq, hd = q.shape
     Hkv = k_pool.shape[1]
     q4 = q.reshape(B, Hkv, Hq // Hkv, hd)
-    out = _paged(q4, k_pool, v_pool, table, pos, step, window=window,
-                 interpret=interpret)
+    out = _call(_paged, q4, k_pool, v_pool, table, pos, step, window=window,
+                interpret=interpret)
     return out.reshape(B, Hq, hd)
 
 
@@ -116,14 +126,9 @@ def tte_sample(logits, u, *, bv: int = 2048,
     """Fused competing-exponential sampler: (B, V) -> (event, t_min).
 
     Pads the vocab axis with neutral entries (rate ~ e^-100: never wins).
-    ``interpret=None`` resolves by backend: Mosaic lowering on TPU, the
-    Pallas interpreter elsewhere — so the serving engine's Pallas sampling
-    path is portable without call-site branching.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     V = logits.shape[1]
     b = min(bv, max(256, 1 << (V - 1).bit_length()))
     lp = _pad_axis(logits.astype(jnp.float32), 1, b, value=-100.0)
     up = _pad_axis(u.astype(jnp.float32), 1, b, value=0.5)
-    return _tte(lp, up, bv=b, interpret=interpret)
+    return _call(_tte, lp, up, bv=b, interpret=interpret)

@@ -57,7 +57,7 @@ def _paged_kernel(tbl_ref, stp_ref, q_ref, k_ref, v_ref, pos_ref, o_ref,
         q = q_ref[0, 0].astype(jnp.float32)           # (G, hd)
         k = k_ref[0, 0].astype(jnp.float32)           # (bs, hd)
         v = v_ref[0, 0].astype(jnp.float32)
-        p = pos_ref[...]                              # (1, bs) int32
+        p = pos_ref[0]                                # (1, bs) int32
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         valid = (p >= 0) & (p <= step) & (p > step - W)
@@ -102,9 +102,11 @@ def paged_decode_attention(q, k_pool, v_pool, table, pos, step, *,
             pl.BlockSpec((1, 1, G, hd), lambda b, h, i, tbl, stp: (b, h, 0, 0)),
             pl.BlockSpec((1, 1, bs, hd), _blk),
             pl.BlockSpec((1, 1, bs, hd), _blk),
-            pl.BlockSpec((1, bs),
+            # (1, 1, bs) block over pos as (NB, 1, bs): its last two dims
+            # equal the array's, which the TPU tiling rule accepts
+            pl.BlockSpec((1, 1, bs),
                          lambda b, h, i, tbl, stp: (jnp.maximum(tbl[b, i], 0),
-                                                    0)),
+                                                    0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, G, hd),
                                lambda b, h, i, tbl, stp: (b, h, 0, 0)),
@@ -120,4 +122,4 @@ def paged_decode_attention(q, k_pool, v_pool, table, pos, step, *,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, hd), q.dtype),
         interpret=interpret,
     )(table.astype(jnp.int32), step.astype(jnp.int32), q, k_pool, v_pool,
-      pos.astype(jnp.int32))
+      pos.astype(jnp.int32).reshape(pos.shape[0], 1, bs))

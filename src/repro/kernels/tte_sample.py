@@ -32,6 +32,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 BIG = 3.4e38
+LANES = 128          # outputs are written lane-dense, then column 0 is kept
 
 
 def _tte_kernel(lg_ref, u_ref, evt_ref, tmin_ref, best_t, best_i, *, bv: int):
@@ -43,51 +44,63 @@ def _tte_kernel(lg_ref, u_ref, evt_ref, tmin_ref, best_t, best_i, *, bv: int):
         best_t[...] = jnp.full_like(best_t, BIG)
         best_i[...] = jnp.zeros_like(best_i)
 
-    lg = lg_ref[...].astype(jnp.float32)         # (1, bv)
+    lg = lg_ref[...].astype(jnp.float32)         # (bb, bv)
     u = u_ref[...].astype(jnp.float32)
     u = jnp.clip(u, 1e-12, 1.0 - 1e-12)
-    t = -jnp.exp(-lg) * jnp.log(u)               # (1, bv)
-    idx = iv * bv + jax.lax.broadcasted_iota(jnp.int32, (1, bv), 1)
-    # local (min, argmin) of this tile, 2-D shapes throughout (TPU-friendly)
-    loc_t = jnp.min(t, axis=1)[0]
-    loc_i = idx[0, jnp.argmin(t, axis=1)[0]]
-    better = loc_t < best_t[0, 0]
-    best_i[0, 0] = jnp.where(better, loc_i, best_i[0, 0])
-    best_t[0, 0] = jnp.where(better, loc_t, best_t[0, 0])
+    t = -jnp.exp(-lg) * jnp.log(u)
+    idx = iv * bv + jax.lax.broadcasted_iota(jnp.int32, t.shape, 1)
+    # tile (min, first argmin): lowest index among the tile's minima
+    loc_t = jnp.min(t, axis=1, keepdims=True)                     # (bb, 1)
+    loc_i = jnp.min(jnp.where(t == loc_t, idx, jnp.iinfo(jnp.int32).max),
+                    axis=1, keepdims=True)
+    better = loc_t < best_t[...]                 # strict: earlier tile wins ties
+    best_i[...] = jnp.where(better, loc_i, best_i[...])
+    best_t[...] = jnp.where(better, loc_t, best_t[...])
 
     @pl.when(iv == nv - 1)
     def _finish():
-        evt_ref[0] = best_i[0, 0]
-        tmin_ref[0] = best_t[0, 0]
+        evt_ref[...] = best_i[...]
+        tmin_ref[...] = best_t[...]
+
+
+def _row_block(B: int) -> int:
+    """Rows per grid step: 8 (one sublane tile) when it divides the batch,
+    else the whole batch (a block equal to the array's dim is always legal)."""
+    return 8 if B % 8 == 0 else B
 
 
 def tte_sample(logits, u, *, bv: int = 2048, interpret: bool = False
                ) -> Tuple[jax.Array, jax.Array]:
     """logits, u: (B, V) -> (event (B,) int32, t_min (B,) fp32).
 
-    V must be divisible by bv (ops.py pads with neutral entries).
+    V must be divisible by bv, and bv by 128 (ops.py pads with neutral
+    entries).  The running (min, argmin) lives in lane-dense (bb, 128) VMEM
+    scratch and is written out the same way; column 0 is the result.
     """
     B, V = logits.shape
+    bb = _row_block(B)
     kern = functools.partial(_tte_kernel, bv=bv)
     evt, tmin = pl.pallas_call(
         kern,
-        grid=(B, V // bv),
+        grid=(B // bb, V // bv),
         in_specs=[
-            pl.BlockSpec((1, bv), lambda b, iv: (b, iv)),
-            pl.BlockSpec((1, bv), lambda b, iv: (b, iv)),
+            pl.BlockSpec((bb, bv), lambda b, iv: (b, iv)),
+            pl.BlockSpec((bb, bv), lambda b, iv: (b, iv)),
         ],
         out_specs=[
-            pl.BlockSpec((1,), lambda b, iv: (b,)),
-            pl.BlockSpec((1,), lambda b, iv: (b,)),
+            pl.BlockSpec((bb, LANES), lambda b, iv: (b, 0)),
+            pl.BlockSpec((bb, LANES), lambda b, iv: (b, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B,), jnp.int32),
-            jax.ShapeDtypeStruct((B,), jnp.float32),
+            jax.ShapeDtypeStruct((B, LANES), jnp.int32),
+            jax.ShapeDtypeStruct((B, LANES), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, 1), jnp.int32),
+            pltpu.VMEM((bb, LANES), jnp.float32),
+            pltpu.VMEM((bb, LANES), jnp.int32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(logits, u)
-    return evt, tmin
+    return evt[:, 0], tmin[:, 0]

@@ -50,15 +50,6 @@ _COLL_RE = re.compile(
 _SHAPE_RE = re.compile(r"(\w+)\[([\d,]*)\]")
 
 
-def _cost_dict(compiled) -> Dict:
-    """Version-tolerant ``compiled.cost_analysis()``: older JAX returns a
-    one-element list of dicts, newer JAX the dict itself."""
-    ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca
-
-
 def _shape_bytes(shape_str: str) -> int:
     total = 0
     for dt, dims in _SHAPE_RE.findall(shape_str):
@@ -108,7 +99,7 @@ def _count_one(cfg: ModelConfig, shape: InputShape, mesh,
     step = build_step_fn(cfg, shape, moe_impl)
     with mesh:
         c = jax.jit(step, in_shardings=shardings).lower(*args).compile()
-    ca = _cost_dict(c)
+    ca = c.cost_analysis() or {}
     return {"flops": float(ca.get("flops", 0.0)),
             "bytes": float(ca.get("bytes accessed", 0.0)),
             "collectives": collective_bytes(c.as_text())}
@@ -209,7 +200,7 @@ def dryrun_one(arch: str, shape_name: str, *, multi_pod: bool = False,
         t_compile = time.time() - t0
 
     mem = compiled.memory_analysis()
-    cost_loop = _cost_dict(compiled)
+    cost_loop = compiled.cost_analysis() or {}
 
     # Exact FLOP/byte/collective counts: XLA's CPU cost analysis counts
     # while-loop bodies ONCE, so the scanned deployment graph undercounts by

@@ -2,33 +2,19 @@
 
 Defined as a function — importing this module never touches jax device state,
 so tests and benches keep seeing 1 CPU device; only ``dryrun.py`` forces 512
-host devices (and only in its own process).
-
-``jax.sharding.AxisType`` (and the matching ``axis_types=`` kwarg of
-``jax.make_mesh``) only exists in newer JAX releases; older ones implicitly
-build Auto meshes.  ``_make_mesh`` passes the explicit Auto types when the
-installed JAX supports them and silently omits them otherwise — the resulting
-mesh semantics are identical (Auto is the default everywhere).
+host devices (and only in its own process).  Every axis is Auto.
 """
 from __future__ import annotations
 
-import inspect
 from typing import Sequence
 
 import jax
 
-# getattr (not attribute access) — newer JAX raises a deprecation
-# AttributeError through module __getattr__ when the symbol is gone.
-_AXIS_TYPE = getattr(jax.sharding, "AxisType", None)
-_HAS_AXIS_TYPES_KW = "axis_types" in inspect.signature(jax.make_mesh).parameters
-
 
 def _make_mesh(shape: Sequence[int], axes: Sequence[str]):
-    """Version-tolerant ``jax.make_mesh`` with all-Auto axis types."""
-    if _AXIS_TYPE is not None and _HAS_AXIS_TYPES_KW:
-        return jax.make_mesh(tuple(shape), tuple(axes),
-                             axis_types=(_AXIS_TYPE.Auto,) * len(axes))
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    """``jax.make_mesh`` with all-Auto axis types."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -20,6 +20,7 @@ import numpy as np
 from repro.configs import get_config
 from repro.data import SimulatorConfig, generate_dataset
 from repro.data import vocab as V
+from repro.launch.runtime import served_config, use_compile_cache
 from repro.models import init_params
 from repro.serve import BatchedEngine, Request
 from repro.train import restore
@@ -44,6 +45,7 @@ class _EngineShard:
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="delphi-2m")
     ap.add_argument("--requests", type=int, default=16)
@@ -66,9 +68,7 @@ def main():
     if args.prefill_chunk_tokens is not None and args.cache != "paged":
         ap.error("--prefill-chunk-tokens requires --cache paged")
 
-    cfg = get_config(args.arch)
-    if jax.default_backend() == "cpu":
-        cfg = cfg.replace(dtype="float32")
+    cfg = served_config(get_config(args.arch))
     params = init_params(cfg, jax.random.PRNGKey(args.seed))
     if args.ckpt:
         params = restore(args.ckpt, params)

@@ -20,11 +20,13 @@ import numpy as np
 from repro.configs import get_config
 from repro.data import (SimulatorConfig, batches, dataset_stats,
                         generate_dataset, pack_trajectories)
+from repro.launch.runtime import served_config, use_compile_cache
 from repro.models import init_params, param_count
 from repro.train import OptimizerConfig, save, train_loop
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="delphi-2m")
     ap.add_argument("--steps", type=int, default=200)
@@ -38,9 +40,7 @@ def main():
     ap.add_argument("--out", default=None)
     args = ap.parse_args()
 
-    cfg = get_config(args.arch, reduced=args.reduced)
-    if jax.default_backend() == "cpu":
-        cfg = cfg.replace(dtype="float32")
+    cfg = served_config(get_config(args.arch, reduced=args.reduced))
     params = init_params(cfg, jax.random.PRNGKey(args.seed))
     print(f"{cfg.name}: {param_count(params):,} params "
           f"({jax.default_backend()} backend, {len(jax.devices())} devices)")

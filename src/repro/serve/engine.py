@@ -169,6 +169,15 @@ class BlockAllocator:
             raise RuntimeError("double free in the paged block allocator")
 
 
+def _current_device() -> jax.Device:
+    """The device ``jax.default_device`` names in this thread, else the
+    first device."""
+    d = jax.config.jax_default_device
+    if isinstance(d, str):
+        return jax.devices(d)[0]
+    return d if d is not None else jax.devices()[0]
+
+
 def _next_pow2(n: int) -> int:
     return 1 << max(n - 1, 1).bit_length() if n > 1 else 1
 
@@ -218,6 +227,8 @@ def _pack(adv):
 
 
 def _tick_core(params, cache, state, u, cfg: ModelConfig, kn: _Knobs):
+    """Decode every slot one event, sample, advance: -> (cache, state,
+    packed host columns, the (slots, V) float32 logits sampled from)."""
     batch = {"tokens": state["last"][:, None]}
     if cfg.age_encoding:
         batch["ages"] = state["age"][:, None]
@@ -234,7 +245,7 @@ def _tick_core(params, cache, state, u, cfg: ModelConfig, kn: _Knobs):
         "max_new": state["max_new"],
         "active": state["active"] & ~adv["finished"],
     }
-    return d["cache"], new_state, _pack(adv)
+    return d["cache"], new_state, _pack(adv), lg
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "kn"),
@@ -248,8 +259,7 @@ def _tick_u_jit(params, cache, state, u, *, cfg, kn):
 def _tick_rng_jit(params, cache, state, key, *, cfg, kn):
     key, sub = jax.random.split(key)
     u = jax.random.uniform(sub, (kn.slots, kn.vocab))
-    cache, state, packed = _tick_core(params, cache, state, u, cfg, kn)
-    return cache, state, packed, key
+    return (*_tick_core(params, cache, state, u, cfg, kn), key)
 
 
 def _prefill_core(params, tokens, ages, last_idx, age0, lengths, max_new, u,
@@ -293,6 +303,30 @@ def _prefill_rng_jit(params, tokens, ages, last_idx, age0, lengths, max_new,
     cache_rows, rows, packed, lg = _prefill_core(
         params, tokens, ages, last_idx, age0, lengths, max_new, u, cfg, kn)
     return cache_rows, rows, packed, lg, key
+
+
+def _prefill_inputs(prompts, nb: int, sb: int):
+    """Right-padded (nb, sb) prefill arrays for ``prompts`` — a list of
+    (tokens, ages or None, events still to sample) — as (tokens, ages,
+    last_idx, age0, lengths, max_new).  Batch rows past the prompts clone
+    row 0: computed and discarded."""
+    tokens = np.zeros((nb, sb), np.int32)
+    ages = np.zeros((nb, sb), np.float32)
+    age0 = np.zeros((nb,), np.float32)
+    lengths = np.full((nb,), len(prompts[0][0]), np.int32)
+    max_new = np.full((nb,), 1, np.int32)
+    for j, (toks, ags, remaining) in enumerate(prompts):
+        S = len(toks)
+        tokens[j, :S] = toks
+        if ags is not None:
+            ages[j, :S] = ags
+            ages[j, S:] = ags[-1]
+            age0[j] = np.float32(ags[-1])
+        lengths[j] = S
+        max_new[j] = remaining
+    tokens[len(prompts):] = tokens[0]
+    ages[len(prompts):] = ages[0]
+    return tokens, ages, lengths - 1, age0, lengths, max_new
 
 
 def _fork_rows_core(lg, u, age0, lengths, max_new, kn: _Knobs):
@@ -457,8 +491,8 @@ def _chunk_arrays(toks, ags, start: int, n: int, bs: int, table_row):
     positions[0, :n] = np.arange(start, start + n, dtype=np.int32)
     # a chunk starting at the prompt head carries a zero-width context:
     # beyond saving work, identical KV widths keep the budget-infinity
-    # chunk bit-identical to the monolithic prefill (a masked-out pad
-    # block would reassociate the softmax reductions)
+    # chunk at the monolithic prefill's softmax reduction widths (a
+    # masked-out pad block would reassociate them)
     first = start // bs
     nctx = _next_pow2(first) if first else 0
     ctx_ids = np.zeros((1, nctx), np.int32)
@@ -658,6 +692,16 @@ class BatchedEngine:
             "max_new": jnp.ones((slots,), jnp.int32),
             "active": jnp.zeros((slots,), bool),
         }
+        # one device holds this engine's params, pool and state: the one
+        # current at construction (``jax.default_device``), so replicas
+        # built under different defaults run on different chips.  Every
+        # jitted call follows its committed inputs to that device.
+        self.device = _current_device()
+        self.params, self.cache, self._state, self._rng = jax.device_put(
+            (params, self.cache, self._state, self._rng), self.device)
+        # (slots, V) float32 logits the last tick sampled from, left on the
+        # device: what checks against a reference read (never fetched here)
+        self.last_logits: Optional[jax.Array] = None       # guarded-by: engine-thread
         self.slot_req: List[Optional[Request]] = [None] * slots  # guarded-by: engine-thread
         self.pending: List[Request] = []                   # guarded-by: _lock
         self.completed: List[Request] = []
@@ -1336,7 +1380,8 @@ class BatchedEngine:
                     for slot in self._prefills:
                         tab[slot, :] = -1
                 pc = self.cache["self"]
-                self.cache = {"self": pc._replace(table=jnp.asarray(tab))}
+                self.cache = {"self": pc._replace(
+                    table=jax.device_put(tab, self.device))}
                 self._table_dirty = False
 
     # -- admission: bucketed batched prefill --------------------------------
@@ -1474,44 +1519,46 @@ class BatchedEngine:
                                     if not r.done and r not in self.slot_req]
             raise
 
+    def _prefill_shape(self, lens: List[int]) -> Tuple[int, int]:
+        """(batch, seq) an admission group with prompt lengths ``lens``
+        prefills at."""
+        n = len(lens)
+        if max(lens) > self.max_context:
+            return n, max(lens)              # solo over-width admission
+        if self.bucketed:
+            # never bucket past the ring width: a pad-rounded S > W would
+            # evict valid prompt context via the S>W ring pack
+            return (min(_next_pow2(n), self.slots),
+                    min(self._seq_bucket(max(lens)), self.max_context))
+        return n, max(lens)
+
+    def prefill_logits(self, tokens, ages=None) -> np.ndarray:
+        """(V,) float32 logits one prompt's admission samples its first
+        event from, computed by the engine's own prefill executable (its
+        shape bucket, dtype and device) — for checks against a reference.
+        Touches no slot, block or queue."""
+        toks = np.asarray(tokens, np.int32)
+        ags = None if ages is None else np.asarray(ages, np.float32)
+        nb, sb = self._prefill_shape([len(toks)])
+        u = np.full((nb, self.cfg.vocab_size), 0.5, np.float32)
+        *_, lg = _prefill_u_jit(
+            self.params, *map(jnp.asarray, _prefill_inputs(
+                [(toks, ags, 1)], nb, sb)),
+            jnp.asarray(u), cfg=self.cfg, kn=self._kn)
+        return np.asarray(lg[0])
+
     def _admit_group_inner(self, group: List[Request], slot_ids: List[int],
                            injected: bool):
         n = len(group)
         prompts = [self._prompt_state(r) for r in group]
         lens = [len(p[0]) for p in prompts]
-        if max(lens) > self.max_context:
-            sb, nb = max(lens), n            # solo over-width admission
-        elif self.bucketed:
-            # never bucket past the ring width: a pad-rounded S > W would
-            # evict valid prompt context via the S>W ring pack
-            sb = min(self._seq_bucket(max(lens)), self.max_context)
-            nb = min(_next_pow2(n), self.slots)
-        else:
-            sb, nb = max(lens), n
+        nb, sb = self._prefill_shape(lens)
         self.prefill_shapes.add((nb, sb))
 
-        tokens = np.zeros((nb, sb), np.int32)
-        ages = np.zeros((nb, sb), np.float32)
-        age0 = np.zeros((nb,), np.float32)
-        lengths = np.full((nb,), lens[0], np.int32)
-        max_new = np.full((nb,), 1, np.int32)
-        for j, (toks, ags, remaining) in enumerate(prompts):
-            S = lens[j]
-            tokens[j, :S] = toks
-            if ags is not None:
-                ages[j, :S] = ags
-                ages[j, S:] = ags[-1]
-                age0[j] = np.float32(ags[-1])
-            lengths[j] = S
-            max_new[j] = remaining
-        tokens[n:] = tokens[0]       # padded admission rows: clones of row 0,
-        ages[n:] = ages[0]           # computed and discarded
-        last_idx = lengths - 1
-
         hold = group[0].hold            # hold parents admit solo
-        args = (self.params, jnp.asarray(tokens), jnp.asarray(ages),
-                jnp.asarray(last_idx), jnp.asarray(age0), jnp.asarray(lengths),
-                jnp.asarray(max_new))
+        inputs = _prefill_inputs(prompts, nb, sb)
+        age0 = inputs[3]
+        args = (self.params, *map(jnp.asarray, inputs))
         if injected or hold:
             # a hold admission samples nothing: filler uniforms keep the
             # prefill on the shared injected executable, the sampled row is
@@ -1962,13 +2009,14 @@ class BatchedEngine:
             for i in active:
                 r = self.slot_req[i]
                 u[i] = r.uniforms[len(r.out_tokens)]
-            self.cache, self._state, packed = _tick_u_jit(
-                self.params, self.cache, self._state, jnp.asarray(u),
+            self.cache, self._state, packed, self.last_logits = _tick_u_jit(
+                self.params, self.cache, self._state,
+                jax.device_put(u, self.device),
                 cfg=self.cfg, kn=self._kn)
         else:
-            self.cache, self._state, packed, self._rng = _tick_rng_jit(
-                self.params, self.cache, self._state, self._rng,
-                cfg=self.cfg, kn=self._kn)
+            (self.cache, self._state, packed, self.last_logits,
+             self._rng) = _tick_rng_jit(self.params, self.cache, self._state,
+                                        self._rng, cfg=self.cfg, kn=self._kn)
         self._slot_pos[active] += 1  # mirror the in-graph step advance
         arr = self._fetch(packed)    # ONE sync per tick
         for slot in active:

@@ -559,8 +559,8 @@ def ring_reference_futures(params, cfg, tokens, ages=None, *, n: int,
         for j in range(n):
             if live[j]:
                 u[j] = uniforms[j, len(out_t[j])]
-        cache, state, packed = _tick_u_jit(params, cache, state,
-                                           jnp.asarray(u), cfg=cfg, kn=kn)
+        cache, state, packed, _ = _tick_u_jit(params, cache, state,
+                                              jnp.asarray(u), cfg=cfg, kn=kn)
         arr = np.asarray(packed)
         for j in range(n):
             if live[j]:
@@ -735,8 +735,8 @@ def chunked_reference_trajectory(params, cfg, tokens, ages=None, *,
             table_dirty = False
         u = np.full((slots, V), 0.5, np.float32)
         u[0] = uniforms[len(out_t)]
-        cache, state, packed = _tick_u_jit(params, cache, state,
-                                           jnp.asarray(u), cfg=cfg, kn=kn)
+        cache, state, packed, _ = _tick_u_jit(params, cache, state,
+                                              jnp.asarray(u), cfg=cfg, kn=kn)
         apply(np.asarray(packed)[:, 0])
         pos += 1
     return out_t, out_a

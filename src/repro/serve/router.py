@@ -45,8 +45,10 @@ Run:  ``repro-serve --config delphi-2m --reduced --replicas 2``
 from __future__ import annotations
 
 import http.client
+import importlib.util
 import itertools
 import json
+import os
 import subprocess
 import sys
 import threading
@@ -321,6 +323,22 @@ class ReplicaHandle:
 # ---------------------------------------------------------------------------
 # Replica supervisor
 # ---------------------------------------------------------------------------
+def _refuse_on_tpu_host(n: int) -> None:
+    """Raise where a spawned JAX process would find a TPU, judged without
+    initialising JAX here: ``JAX_PLATFORMS`` lets it, and the TPU runtime
+    is installed."""
+    platforms = os.environ.get("JAX_PLATFORMS", "")
+    if platforms and "tpu" not in platforms.split(","):
+        return
+    if importlib.util.find_spec("libtpu") is None:
+        return
+    raise RuntimeError(
+        f"subprocess replicas on a TPU host: each of the {n} processes "
+        f"would claim every chip, and all but one would fail on the chip's "
+        f"lock — use in-process replicas (repro-serve --replica-mode "
+        f"inprocess), which put one replica on each chip")
+
+
 class ReplicaSupervisor:
     """Owns the replica set: spawn/boot/adopt, health-probe, drain-stop.
 
@@ -350,12 +368,18 @@ class ReplicaSupervisor:
         """Boot ``n`` replicas inside this process, each a fresh backend
         behind its own ``InferenceServer`` on an ephemeral port — the unit
         of the router tests/benchmarks (engines share the module-level jit
-        cache, so replica 2..n compile nothing new)."""
+        cache, so replica 2..n compile nothing new).  Replica ``i`` is built
+        under ``jax.default_device(jax.devices()[i % n_devices])``, so an
+        engine replica keeps its params, pool and state on its own chip."""
+        import jax
         from repro.serve.server import InferenceServer
+        devices = jax.devices()
         handles = []
         try:
             for i in range(n):
-                server = InferenceServer(make_backend(i), port=0,
+                with jax.default_device(devices[i % len(devices)]):
+                    backend = make_backend(i)
+                server = InferenceServer(backend, port=0,
                                          request_timeout=request_timeout
                                          ).start()
                 handles.append(ReplicaHandle(
@@ -376,7 +400,13 @@ class ReplicaSupervisor:
         """Spawn ``n`` ``repro-serve`` subprocesses.  ``replica_argv(i,
         port)`` returns the CLI argv for replica ``i`` bound to ``port``
         (it must include ``--port <port>``); each replica is polled on
-        ``/v1/manifest`` until it answers or ``ready_timeout`` passes."""
+        ``/v1/manifest`` until it answers or ``ready_timeout`` passes.
+
+        Refused where the children would find a TPU: each child process
+        would claim every chip of the host, and all but the first would
+        fail on the chip's lock.  There, ``in_process`` puts one replica on
+        each chip."""
+        _refuse_on_tpu_host(n)
         py = python or sys.executable
         handles: List[ReplicaHandle] = []
         try:
@@ -1119,15 +1149,16 @@ def _replica_argv_base(args) -> List[str]:
 
 
 def _shared_params_backend_factory(args) -> Callable[[int], object]:
-    """In-process replicas share ONE parameter tree (and the module-level
-    jit cache), so N replicas cost N KV pools — not N models."""
-    from repro.serve.server import _build_backend
+    """In-process replicas share ONE parameter tree per device (and the
+    module-level jit cache), so N replicas on one device cost N KV pools —
+    not N models; a replica on another chip holds its own copy there."""
+    from repro.serve.server import build_backend
     if args.artifact:
         def make_backend(i: int):
             from repro.api.client import ArtifactBackend
             return ArtifactBackend(args.artifact)
         return make_backend
-    first = _build_backend(args)
+    first = build_backend(args)
     from repro.api.client import EngineBackend, LocalBackend
     if isinstance(first, LocalBackend):
         made = [first]

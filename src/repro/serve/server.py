@@ -443,7 +443,9 @@ class _Handler(BaseHTTPRequestHandler):
 # ---------------------------------------------------------------------------
 # CLI: the `repro-serve` console script
 # ---------------------------------------------------------------------------
-def _build_backend(args):
+def build_backend(args):
+    """The backend ``repro-serve`` serves for a parsed :func:`build_parser`
+    namespace."""
     if args.artifact:
         from repro.api.client import ArtifactBackend
         return ArtifactBackend(args.artifact)
@@ -452,9 +454,9 @@ def _build_backend(args):
     import jax
     from repro.api.client import EngineBackend, LocalBackend
     from repro.configs import get_config
+    from repro.launch.runtime import served_config
     from repro.models import init_params
-    cfg = get_config(args.config, reduced=args.reduced).replace(
-        dtype="float32")
+    cfg = served_config(get_config(args.config, reduced=args.reduced))
     params = init_params(cfg, jax.random.PRNGKey(args.seed))
     if args.backend == "local":
         return LocalBackend(params, cfg)
@@ -490,7 +492,7 @@ def _build_backend(args):
     return backend
 
 
-def main(argv: Optional[List[str]] = None) -> int:
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser(
         prog="repro-serve",
         description="Serve a repro.api backend over the v%s JSON/SSE wire "
@@ -535,7 +537,8 @@ def main(argv: Optional[List[str]] = None) -> int:
                     help="--cache paged: prefill prompts in N-token chunks "
                          "interleaved with decode ticks instead of one "
                          "monolithic pass (N must be a multiple of "
-                         "--block-size; bit-identical outputs either way)")
+                         "--block-size; outputs agree within the float32 "
+                         "logit tolerance either way)")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--request-timeout", type=float, default=300.0,
                     help="seconds before an in-flight request is expired "
@@ -555,7 +558,13 @@ def main(argv: Optional[List[str]] = None) -> int:
                             "replicas instead of starting any")
     ap.add_argument("--verbose", action="store_true",
                     help="log one line per HTTP request")
-    args = ap.parse_args(argv)
+    return ap
+
+
+def main(argv: Optional[List[str]] = None) -> int:
+    from repro.launch.runtime import use_compile_cache
+    use_compile_cache()
+    args = build_parser().parse_args(argv)
 
     if args.replicas > 1 or args.replica_urls:
         from repro.serve.router import ROUTER_NAME, build_router
@@ -573,7 +582,7 @@ def main(argv: Optional[List[str]] = None) -> int:
             print("repro-serve: shutting down")
         return 0
 
-    backend = _build_backend(args)
+    backend = build_backend(args)
     server = InferenceServer(backend, args.host, args.port,
                              request_timeout=args.request_timeout,
                              quiet=not args.verbose)

@@ -21,6 +21,7 @@ from repro.api import (ApiError, Client, GenerateRequest, RemoteBackend,
 from repro.api.client import EngineBackend
 from repro.configs import get_config
 from repro.core import init_delphi
+from repro.core.parity import check_trajectory, logit_tolerance
 from repro.serve.server import InferenceServer
 
 TOKS = [3, 10, 20]
@@ -107,11 +108,18 @@ def test_remote_bit_identical_to_local(setup):
                            uniforms=u)
     r_rem = remote.generate(tokens=TOKS, ages=AGES, max_new=max_new,
                             uniforms=u)
+    # the server's own engine, called in-process: the same executable, so
+    # the wire must add or lose nothing, bit for bit
+    r_same = Client(server.backend).generate(tokens=TOKS, ages=AGES,
+                                             max_new=max_new, uniforms=u)
     assert len(r_rem.tokens) > 0
-    assert r_rem.tokens == r_loc.tokens          # bit-identical events
+    assert (r_rem.tokens, r_rem.ages) == (r_same.tokens, r_same.ages)
     assert r_rem.prompt_tokens == TOKS and r_rem.prompt_ages == AGES
     assert r_rem.backend == "remote[engine]"
-    np.testing.assert_allclose(r_rem.ages, r_loc.ages, rtol=0.08)
+    # the local backend is compiled separately: both follow the reference
+    for r in (r_rem, r_loc):
+        check_trajectory(params, cfg, TOKS, AGES, r.tokens, r.ages, u,
+                         tol=logit_tolerance(cfg), max_new=max_new)
 
 
 def test_remote_stream_matches_generate(setup):

@@ -249,6 +249,7 @@ def test_ssd_intra_vs_ref(key, BH, C, Q, P, N, dtype):
 TTE_CASES = [
     (1, 64), (3, 1289), (2, 2048), (1, 50304),
     (2, 100),   # heavy padding path
+    (16, 1289), (8, 4096),   # 8-row blocks: two row blocks; two vocab tiles
 ]
 
 

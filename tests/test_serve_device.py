@@ -7,6 +7,7 @@ import pytest
 
 from repro.configs import get_config
 from repro.core import generate_trajectories, init_delphi
+from repro.core.parity import check_trajectory, logit_tolerance
 from repro.sdk import InferenceSession, export_model
 from repro.serve import BatchedEngine, Request
 from repro.serve import engine as engine_mod
@@ -44,12 +45,13 @@ def _run_engine(params, cfg, *, uniforms, max_new=6, max_context=64,
 
 
 def test_engine_vs_sdk_parity(setup):
-    """Claim C2/C3: engine in-graph generation == SDK host loop under the
-    same injected uniforms — bit-exact event sequence.
+    """Claim C2/C3: engine in-graph generation and the SDK host loop, under
+    the same injected uniforms, both follow the float32 reference logits.
 
-    Horizon matches test_sdk's core-vs-SDK parity test: tokens are compared
-    exactly; ages loosely (jit-vs-eager fusion rounding compounds through
-    exp(-logit), same caveat as there)."""
+    The two are separately compiled (in-graph jit vs the exported graph
+    driven from the host), so each is held to the reference along its own
+    path (``repro.core.parity``) instead of to the other: where two
+    candidates' waiting times tie within fp noise, either may win."""
     params, cfg, d = setup
     max_new = 6
     u = _uniforms(max_new, cfg.vocab_size)
@@ -59,15 +61,12 @@ def test_engine_vs_sdk_parity(setup):
                                    uniforms=u, max_age=1e9)
     req, _ = _run_engine(params, cfg.replace(max_age=1e9), uniforms=u,
                          max_new=max_new)
-    assert req.out_tokens == sdk["tokens"]
-    assert len(req.out_ages) == len(sdk["ages"])
-    # Early waiting times agree tightly (same uniforms; fp32 engine state vs
-    # fp64 SDK host).  Later ages are NOT compared against the SDK: the
-    # untrained model's decade-scale waiting times drive the high-frequency
-    # age encoding chaotically, so fp32-vs-fp64 age feedback diverges after
-    # ~2 events while the event sequence stays identical.  Tight full-horizon
-    # age parity is asserted fp32-vs-fp32 in test_engine_vs_core_parity.
-    np.testing.assert_allclose(req.out_ages[:2], sdk["ages"][:2], rtol=1e-3)
+    tol = logit_tolerance(cfg)
+    for toks, ages in ((req.out_tokens, req.out_ages),
+                       (sdk["tokens"], sdk["ages"])):
+        assert len(toks) > 0
+        check_trajectory(params, cfg, TOKS, AGES, toks, ages, u, tol=tol,
+                         max_new=max_new, max_age=1e9)
     assert all(b >= a for a, b in zip(req.out_ages, req.out_ages[1:]))
 
 
