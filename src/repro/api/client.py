@@ -633,7 +633,9 @@ class EngineBackend(InferenceBackend):
         return [self._finish(req, er) for req, er in pairs]
 
     def generate(self, req: GenerateRequest) -> TrajectoryResult:
-        return self.generate_batch([req])[0]
+        with self.engine.spans.span("api.generate",
+                                    request_id=req.request_id or ""):
+            return self.generate_batch([req])[0]
 
     def sample_futures(self, req: FuturesRequest) -> FuturesResult:
         """Monte-Carlo futures through the engine's prefix-sharing ``fork``:
@@ -646,7 +648,13 @@ class EngineBackend(InferenceBackend):
         forgoes the memory savings).  The result carries the pool's
         sharing telemetry in ``FuturesResult.sharing`` — engine-lifetime
         cumulative counters snapshotted at completion, not per-request
-        deltas."""
+        deltas.  Runs in an ``api.sample_futures`` span (its
+        ``api.futures.*`` phases inside), on the caller's thread."""
+        with self.engine.spans.span("api.sample_futures",
+                                    request_id=req.request_id or ""):
+            return self._sample_futures(req)
+
+    def _sample_futures(self, req: FuturesRequest) -> FuturesResult:
         self._validate_futures(req)
         if req.uniforms is None and req.seed != 0:
             # mirror the generate() contract: the engine's in-graph RNG
@@ -664,26 +672,29 @@ class EngineBackend(InferenceBackend):
              if req.ages is not None else None),
             n=req.n_futures, max_new=req.max_new, uniforms=uniforms,
             request_id=req.request_id, wait_timeout=self.request_timeout)
-        results = []
-        for c in children:
-            if c.error is not None:
-                raise c.error
-            if not c.done:
-                raise RuntimeError("engine stopped before completing a "
-                                   "forked future")
-            results.append(TrajectoryResult(
-                tokens=list(c.out_tokens),
-                ages=[float(a) for a in c.out_ages],
-                prompt_tokens=[int(t) for t in req.tokens],
-                prompt_ages=([float(a) for a in req.ages]
-                             if req.ages is not None else []),
-                backend=self.name))
-        out = self._futures_result(req, results)
-        st = self.engine.pool_stats()
-        out.sharing = {k: st[k] for k in
-                       ("cache", "forks", "preemptions", "shared_blocks",
-                        "shared_blocks_peak", "cow_copies", "prefix_cache")
-                       if k in st}
+        with self.engine.spans.span("api.futures.collect",
+                                    request_id=req.request_id or ""):
+            results = []
+            for c in children:
+                if c.error is not None:
+                    raise c.error
+                if not c.done:
+                    raise RuntimeError("engine stopped before completing a "
+                                       "forked future")
+                results.append(TrajectoryResult(
+                    tokens=list(c.out_tokens),
+                    ages=[float(a) for a in c.out_ages],
+                    prompt_tokens=[int(t) for t in req.tokens],
+                    prompt_ages=([float(a) for a in req.ages]
+                                 if req.ages is not None else []),
+                    backend=self.name))
+            out = self._futures_result(req, results)
+            st = self.engine.pool_stats()
+            out.sharing = {k: st[k] for k in
+                           ("cache", "forks", "preemptions", "shared_blocks",
+                            "shared_blocks_peak", "cow_copies",
+                            "prefix_cache")
+                           if k in st}
         return out
 
     def stream(self, req: GenerateRequest) -> Iterator[TrajectoryEvent]:

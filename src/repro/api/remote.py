@@ -10,9 +10,8 @@ JSON numbers).
 
 Connection policy: the server speaks HTTP/1.1 with keep-alive, so this
 backend holds **one persistent connection** and pipelines sequential JSON
-calls over it instead of paying a TCP handshake per request (the req/s
-delta is measured by ``benchmarks/run.py http``; pass ``keep_alive=False``
-to get the old socket-per-call behaviour).  A stale pooled socket (server
+calls over it instead of paying a TCP handshake per request (pass
+``keep_alive=False`` to get the old socket-per-call behaviour).  A stale pooled socket (server
 restarted, idle timeout) is retried once on a fresh connection.  SSE
 streams are close-delimited and always use a dedicated connection.
 

@@ -8,7 +8,7 @@ our synthetic cohort:
   * events-per-year by age decade (the hazard ramp),
   * ICD-chapter frequency profile (L1 distance model vs data).
 
-Used by ``benchmarks.run calibration`` and ``tests/test_risk.py``.
+Used by ``tests/test_risk.py``.
 """
 from __future__ import annotations
 

@@ -1,7 +1,7 @@
 """Process set-up shared by the entry points: compile cache, served dtype.
 
 Each ``main()`` (``repro-serve``, ``repro.launch.serve``,
-``repro.launch.train``, ``benchmarks/run.py``, ``chip_smoke.py``) calls
+``repro.launch.train``, ``chip_smoke.py``, ``perfbench/run.py``) calls
 :func:`use_compile_cache` first; nothing here runs at import.
 """
 from __future__ import annotations

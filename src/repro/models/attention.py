@@ -220,6 +220,7 @@ def chunked_attention(q, k, v, q_pos, k_pos, *, causal: bool,
     return out[:, :Sq].astype(q.dtype)
 
 
+@jax.named_scope("paged_gather")
 def paged_gather_layer(view: PagedLayerView) -> LayerCache:
     """Reconstruct the dense ring view of one layer's paged cache.
 
@@ -413,6 +414,7 @@ def cache_write(cache: LayerCache, k_new, v_new, step) -> LayerCache:
     return LayerCache(k=k, v=v, pos=pos)
 
 
+@jax.named_scope("cache_write")
 def cache_write_stacked(caches, k_news, v_news, step):
     """One scatter for the whole layer stack (the deferred decode write).
 
@@ -459,6 +461,7 @@ def cache_write_stacked(caches, k_news, v_news, step):
 # ---------------------------------------------------------------------------
 # Full attention layer (self or cross), all modes
 # ---------------------------------------------------------------------------
+@jax.named_scope("attention")
 def attention(params, x, positions, cfg: ModelConfig, *, mode: str,
               cache: Optional[LayerCache] = None, step=None,
               memory=None, memory_pos=None, cross: bool = False,

@@ -110,6 +110,7 @@ def init_mlp(key, cfg: ModelConfig, d: int, d_ff: int):
     }
 
 
+@jax.named_scope("mlp")
 def apply_mlp(params, x, cfg: ModelConfig):
     dt = x.dtype
     if "w_gate" in params:
@@ -143,6 +144,7 @@ def embed_tokens(params, tokens, cfg: ModelConfig):
     return params["embed"].astype(act_dtype(cfg))[tokens]
 
 
+@jax.named_scope("head")
 def logits_head(params, h, cfg: ModelConfig):
     if cfg.tie_embeddings:
         w = params["embed"].astype(h.dtype).T

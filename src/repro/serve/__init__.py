@@ -2,13 +2,12 @@
 HTTP/SSE wire front-end (``repro.serve.server``) and the multi-replica
 prefix-affinity router (``repro.serve.router``) — both imported lazily to
 keep ``import repro.serve`` free of the client API stack."""
-from repro.serve.engine import (BatchedEngine, BlockAllocator,
-                                ReferenceEngine, Request)
+from repro.serve.engine import BatchedEngine, BlockAllocator, Request
 from repro.serve.prefix import (PrefixIndex, SharedBlockPool,
                                 chunked_reference_trajectory, prompt_digests,
                                 ring_reference_futures)
 
-__all__ = ["BatchedEngine", "BlockAllocator", "ReferenceEngine", "Request",
+__all__ = ["BatchedEngine", "BlockAllocator", "Request",
            "SharedBlockPool", "PrefixIndex", "prompt_digests",
            "ring_reference_futures", "chunked_reference_trajectory",
            "InferenceServer", "RouterServer", "ReplicaSupervisor",

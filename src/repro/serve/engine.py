@@ -27,9 +27,9 @@ Design — one jitted ``decode_and_sample`` step per engine tick:
   bootstrap logits are gathered at each prompt's true last token
   (``forward(..., last_index=...)``).
 
-``ReferenceEngine`` below preserves the original host-loop engine (per-slot
-vmap decode + host-side Python sampling) as the before/after benchmark
-baseline — ``benchmarks/run.py serve`` reports both.
+Each phase of a step runs inside a span (``repro.serve.spans``): a
+``jax.profiler`` annotation on the device's clock plus a per-name aggregate
+that ``health_stats()`` reports beside the engine's counters.
 """
 from __future__ import annotations
 
@@ -52,6 +52,7 @@ from repro.models import (decode_step, forward, forward_suffix,
                           make_decode_cache, make_paged_decode_cache,
                           mask_padded_positions)
 from repro.serve.prefix import PrefixIndex, SharedBlockPool
+from repro.serve.spans import Spans
 
 # Module-level so tests can monkeypatch/count device->host transfers: this is
 # the ONLY way the engine moves data off-device.
@@ -89,6 +90,12 @@ class Request:
     # set (before on_done fires) if the engine loop failed this request —
     # waiters must check it rather than trusting out_tokens
     error: Optional[BaseException] = None
+    # time.monotonic() at submit (or fork), first admission to a slot, first
+    # emitted event and termination; None until the engine gets there
+    t_submit: Optional[float] = None
+    t_admit: Optional[float] = None
+    t_first: Optional[float] = None
+    t_done: Optional[float] = None
     # engine bookkeeping: admission order (pool-exhaustion preemption picks
     # the youngest) and the wall-clock deadline stamped at submit when the
     # engine enforces request timeouts
@@ -211,6 +218,7 @@ def _sample_evt(lg, u, kn: _Knobs):
     return evt, jnp.zeros(evt.shape, jnp.float32)
 
 
+@jax.named_scope("sample")
 def _advance(lg, u, age, n_emitted, max_new, next_pos, active, kn: _Knobs):
     evt, tmin = _sample_evt(lg, u, kn)
     return advance_trajectory_state(
@@ -262,6 +270,7 @@ def _tick_rng_jit(params, cache, state, key, *, cfg, kn):
     return (*_tick_core(params, cache, state, u, cfg, kn), key)
 
 
+@jax.named_scope("prefill")
 def _prefill_core(params, tokens, ages, last_idx, age0, lengths, max_new, u,
                   cfg: ModelConfig, kn: _Knobs):
     batch: Dict[str, Any] = {"tokens": tokens}
@@ -718,10 +727,19 @@ class BatchedEngine:
         self._wake = threading.Event()
         self._thread: Optional[threading.Thread] = None
         self._stop_flag = False
-        # instrumentation (asserted on by tests, reported by benchmarks):
-        # bumped on the engine thread, snapshotted under `_lock` by the
-        # cross-thread stats readers (pool_stats / health_stats)
+        # instrumentation (asserted on by tests, read by the benchmark and
+        # /v1/healthz): bumped on the engine thread, snapshotted under
+        # `_lock` by the cross-thread stats readers (pool_stats /
+        # health_stats)
         self.ticks = 0                                     # guarded-by: _lock
+        # active slots summed over ticks; events emitted by ticks and by
+        # admission bootstraps; first admissions to a slot and their
+        # summed submit-to-slot seconds
+        self.slot_ticks = 0                                # guarded-by: _lock
+        self.events_tick = 0                               # guarded-by: _lock
+        self.events_admit = 0                              # guarded-by: _lock
+        self.admitted = 0                                  # guarded-by: _lock
+        self.queue_wait_s = 0.0                            # guarded-by: _lock
         self.host_syncs = 0                                # guarded-by: _lock
         self.admit_batches = 0                             # guarded-by: _lock
         self.preemptions = 0                               # guarded-by: _lock
@@ -731,6 +749,8 @@ class BatchedEngine:
         self.prefill_chunks = 0                            # guarded-by: _lock
         self.suffix_tokens_saved = 0                       # guarded-by: _lock
         self.prefill_shapes: set = set()                   # guarded-by: engine-thread
+        # phase spans of the engine's steps and its callers' requests
+        self.spans = Spans()
         self._kn = _Knobs(
             slots=slots, max_context=max_context,
             is_delphi=self.is_delphi, use_pallas=sampler == "pallas",
@@ -752,6 +772,7 @@ class BatchedEngine:
             raise ValueError("empty prompt")
         req.out_tokens, req.out_ages = [], []
         req._seq = next(self._seq_counter)
+        req.t_submit = time.monotonic()
         if req.request_id is None:
             req.request_id = f"req-{id(self):x}-{req._seq}"
         if self.request_timeout is not None and req._deadline is None:
@@ -824,9 +845,11 @@ class BatchedEngine:
             raise InvalidRequestError(
                 "fork children must be uniformly injected or uniformly "
                 "RNG-sampled (one tick draws from one uniform source)")
+        now = time.monotonic()
         for c in children:
             c.out_tokens, c.out_ages = [], []
             c._seq = next(self._seq_counter)
+            c.t_submit = now
             if c.request_id is None:
                 c.request_id = f"req-{id(self):x}-{c._seq}"
             if self.request_timeout is not None and c._deadline is None:
@@ -898,26 +921,30 @@ class BatchedEngine:
         # when submit() autogenerates the parent id, rebuild so the child
         # ids derive from the real one
         children = self._build_fork_children(parent, n, uniforms, max_new)
-        self.submit(parent)
-        if request_id is None:
-            children = self._build_fork_children(parent, n, uniforms,
-                                                 max_new)
-        waits: List[threading.Event] = []
-        if running:
-            for c in children:
-                evt = threading.Event()
-                c.on_done = lambda _r, _evt=evt: _evt.set()
-                waits.append(evt)
-        self.fork(parent.request_id, children=children)
-        if running:
-            from repro.api.errors import RequestTimeoutError
-            for evt in waits:
-                if not evt.wait(wait_timeout):
-                    raise RequestTimeoutError(
-                        f"engine did not complete the forked futures within "
-                        f"{wait_timeout}s")
-        else:
-            self.run()
+        with self.spans.span("api.futures.submit") as sp:
+            self.submit(parent)
+            sp.stat(request_id=parent.request_id)
+            if request_id is None:
+                children = self._build_fork_children(parent, n, uniforms,
+                                                     max_new)
+            waits: List[threading.Event] = []
+            if running:
+                for c in children:
+                    evt = threading.Event()
+                    c.on_done = lambda _r, _evt=evt: _evt.set()
+                    waits.append(evt)
+            self.fork(parent.request_id, children=children)
+        with self.spans.span("api.futures.wait",
+                             request_id=parent.request_id):
+            if running:
+                from repro.api.errors import RequestTimeoutError
+                for evt in waits:
+                    if not evt.wait(wait_timeout):
+                        raise RequestTimeoutError(
+                            f"engine did not complete the forked futures "
+                            f"within {wait_timeout}s")
+            else:
+                self.run()
         return children
 
     def drop_prefix_cache(self) -> int:
@@ -1014,21 +1041,28 @@ class BatchedEngine:
 
         The single locked accessor HTTP handler threads are allowed to use
         instead of reaching into engine fields directly (RL001 flags the
-        latter as a foreign access).
+        latter as a foreign access).  ``spans`` holds the per-name span
+        aggregates (``repro.serve.spans``).
         """
         with self._lock:
             pending = len(self.pending)
-            ticks = self.ticks
+            counters = {
+                "ticks": self.ticks, "slot_ticks": self.slot_ticks,
+                "events_tick": self.events_tick,
+                "events_admit": self.events_admit,
+                "admitted": self.admitted,
+                "queue_wait_s": self.queue_wait_s}
         # repro-lint: disable=RL001 GIL-atomic scan of the slot list for a
         # monitoring count; entries may be mid-swap but never torn
         active = sum(r is not None for r in self.slot_req)
         return {
             "running": self.running,
-            "ticks": ticks,
+            **counters,
             "pending": pending,
             "active_slots": active,
             "slots": self.slots,
             "memory": self.pool_stats(),
+            "spans": self.spans.snapshot(),
         }
 
     def stop(self, join: bool = True, timeout: float = 60.0) -> None:
@@ -1070,7 +1104,8 @@ class BatchedEngine:
             if progressed:
                 idle = idle_min
             else:
-                self._wake.wait(idle)
+                with self.spans.span("engine.idle"):
+                    self._wake.wait(idle)
                 self._wake.clear()
                 idle = min(idle * 2.0, idle_max)
 
@@ -1105,6 +1140,7 @@ class BatchedEngine:
         and timeout: deregister, mark done, unblock waiters."""
         if error is not None:
             req.error = error
+        req.t_done = time.monotonic()
         req.done = True
         with self._lock:
             if req.request_id is not None:
@@ -1389,24 +1425,48 @@ class BatchedEngine:
         return max(_next_pow2(n), self.min_seq_bucket)
 
     def _admit(self):  # repro-lint: engine-thread-only
-        while True:
-            with self._lock:
-                sel = self._select_admission()
-            if sel is None:
-                return
-            kind, group, slot_ids, injected = sel
-            if kind == "ref":
-                self._admit_ref(group[0], slot_ids[0], injected)
-            elif self._chunked_for(group[0]):
-                # chunked admissions only stage cursor state: the forward
-                # work is metered out by _run_prefill_chunks between ticks.
-                # Cohort-mates all satisfy _chunked_for (over-width prompts
-                # admit solo, so a chunk-eligible head implies an eligible
-                # group).
-                for req, slot in zip(group, slot_ids):
-                    self._admit_chunked(req, slot)
-            else:
-                self._admit_group(group, slot_ids, injected)
+        """Admit pending requests: one ``engine.admit`` span over the
+        selection and every group it admits, with the groups' count, the
+        requests admitted, the first one's id and the prefill shapes."""
+        groups = requests = 0
+        first, shapes = None, []
+        with self.spans.span("engine.admit") as sp:
+            while True:
+                with self._lock:
+                    sel = self._select_admission()
+                if sel is None:
+                    break
+                kind, group, slot_ids, injected = sel
+                groups += 1
+                requests += len(group)
+                if first is None:
+                    first = group[0].request_id
+                if kind == "ref":
+                    self._admit_ref(group[0], slot_ids[0], injected)
+                elif self._chunked_for(group[0]):
+                    # chunked admissions only stage cursor state: the
+                    # forward work is metered out by _run_prefill_chunks
+                    # between ticks.  Cohort-mates all satisfy _chunked_for
+                    # (over-width prompts admit solo, so a chunk-eligible
+                    # head implies an eligible group).
+                    for req, slot in zip(group, slot_ids):
+                        self._admit_chunked(req, slot)
+                else:
+                    shapes.append("%dx%d" % self._prefill_shape(
+                        [self._eff_len(r) for r in group]))
+                    self._admit_group(group, slot_ids, injected)
+            if groups:
+                sp.stat(groups=groups, requests=requests, first=first,
+                        shapes=",".join(shapes))
+
+    def _landed(self, req: Request, now: float) -> None:
+        """A request took a slot: its first admission stamps ``t_admit``
+        and counts its queue wait (a preempted request's re-admission
+        does not)."""
+        if req.t_admit is None:
+            req.t_admit = now
+            self.admitted += 1
+            self.queue_wait_s += now - req.t_submit
 
     def _select_admission(self) -> Optional[  # repro-lint: engine-thread-only holds=_lock
             Tuple[str, List[Request], List[int], bool]]:
@@ -1617,9 +1677,11 @@ class BatchedEngine:
 
         self.admit_batches += 1
         arr = self._fetch(packed)    # ONE sync per admission batch
+        now = time.monotonic()
         for j, (req, slot) in enumerate(zip(group, slot_ids)):
             self.slot_req[slot] = req
             self._slot_pos[slot] = lens[j]
+            self._landed(req, now)
             if self.prefix is not None and lens[j] <= self.max_context \
                     and not req.out_tokens:
                 # register this prompt's blocks; hold parents also index
@@ -1653,7 +1715,7 @@ class BatchedEngine:
                 self._held_logits[slot] = lg[j]
                 self._deactivate.append(slot)
             else:
-                self._apply_host(req, slot, arr[:, j])
+                self.events_admit += self._apply_host(req, slot, arr[:, j])
 
     def _admit_ref(self, req: Request, slot: int, injected: bool) -> None:  # repro-lint: engine-thread-only
         """Admission by pure reference: the whole prompt matched a complete
@@ -1676,6 +1738,7 @@ class BatchedEngine:
         self._table_dirty = True
         self.slot_req[slot] = req
         self._slot_pos[slot] = entry.S
+        self._landed(req, time.monotonic())
         if req.hold:
             self._held_logits[slot] = entry.logits
             self._deactivate.append(slot)
@@ -1695,7 +1758,7 @@ class BatchedEngine:
             self._state, jnp.asarray([slot], jnp.int32), rows)
         self.admit_batches += 1
         arr = self._fetch(packed)       # ONE sync, like any admission batch
-        self._apply_host(req, slot, arr[:, 0])
+        self.events_admit += self._apply_host(req, slot, arr[:, 0])
 
     # -- chunked prefill (prefill/decode interleaving) ------------------------
     def _admit_chunked(self, req: Request, slot: int) -> None:  # repro-lint: engine-thread-only
@@ -1737,6 +1800,7 @@ class BatchedEngine:
         self._table_dirty = True
         self.slot_req[slot] = req
         self._slot_pos[slot] = 0
+        self._landed(req, time.monotonic())
         cursor = len(hits) * bs
         self.suffix_tokens_saved += cursor
         self.chunked_prefills += 1
@@ -1828,7 +1892,7 @@ class BatchedEngine:
             self._state, jnp.asarray([slot], jnp.int32), rows)
         self.admit_batches += 1
         arr = self._fetch(packed)       # ONE sync, like any admission batch
-        self._apply_host(req, slot, arr[:, 0])
+        self.events_admit += self._apply_host(req, slot, arr[:, 0])
 
     # -- fork application (engine thread) ------------------------------------
     def _apply_forks(self) -> bool:  # repro-lint: engine-thread-only
@@ -1841,10 +1905,19 @@ class BatchedEngine:
         The parent is consumed."""
         if not self._fork_ops:
             return False
+        with self.spans.span("engine.fork") as sp:
+            progressed, children = self._apply_fork_ops()
+            sp.stat(children=children)
+        return progressed
+
+    def _apply_fork_ops(self) -> Tuple[bool, int]:  # repro-lint: engine-thread-only
+        """:meth:`_apply_forks`' work: -> (progressed, children of the
+        forks applied)."""
         with self._lock:
             ops = self._fork_ops[:]
             self._fork_ops.clear()
         deferred: List[Tuple[Request, List[Request]]] = []
+        children = 0
         progressed = False
         for parent, kids in ops:
             if parent.done:
@@ -1857,6 +1930,7 @@ class BatchedEngine:
                 for c in kids:
                     self._finalize(c, err)
                 progressed = True
+                children += len(kids)
                 continue
             try:
                 pslot = self.slot_req.index(parent)
@@ -1897,10 +1971,11 @@ class BatchedEngine:
                 if self.paged:
                     self.pool.release(blocks)        # drop the temp ref
             progressed = True
+            children += len(kids)
         if deferred:
             with self._lock:
                 self._fork_ops[:0] = deferred
-        return progressed
+        return progressed, children
 
     def _apply_one_fork(self, parent: Request, kids: List[Request],  # repro-lint: engine-thread-only
                         pslot: int, logits, S: int, age0: float,
@@ -1961,14 +2036,21 @@ class BatchedEngine:
                 jax.tree_util.tree_map(lambda a: a[:k], rows))
             self.admit_batches += 1
             arr = self._fetch(packed)
+            now = time.monotonic()
             for j, (c, s) in enumerate(zip(wave, wave_slots)):
                 self.slot_req[s] = c
                 self._slot_pos[s] = S
-                self._apply_host(c, s, arr[:, j])
+                self._landed(c, now)
+                self.events_admit += self._apply_host(c, s, arr[:, j])
 
-    def _apply_host(self, req: Request, slot: int, col: np.ndarray):
+    def _apply_host(self, req: Request, slot: int, col: np.ndarray) -> int:
+        """One slot's column of a packed sync onto its request: -> the
+        number of events emitted (0 or 1)."""
         evt, age, emit, finished = col
-        if emit >= 0.5:
+        emitted = int(emit >= 0.5)
+        if emitted:
+            if not req.out_tokens:
+                req.t_first = time.monotonic()
             req.out_tokens.append(int(evt))
             if self.is_delphi:
                 req.out_ages.append(float(age))
@@ -1977,50 +2059,73 @@ class BatchedEngine:
         if finished >= 0.5:
             self._release_slot(slot)     # returns paged blocks to the pool
             self._finalize(req)
+        return emitted
 
     # -- the tick ------------------------------------------------------------
     def step(self) -> bool:  # repro-lint: engine-thread-only hot-path
         """One engine tick: control pass (cancel/timeout), admit pending,
         apply fork ops, grow/COW/preempt paged blocks, decode+sample all
-        non-held slots in-graph."""
-        self._apply_control()
-        self._flush_slot_updates()   # deactivations BEFORE slots are reused
+        non-held slots in-graph.  The whole is one ``engine.step`` span,
+        each phase a span inside it."""
+        with self.spans.span("engine.step"):
+            return self._step()
+
+    def _step(self) -> bool:  # repro-lint: engine-thread-only hot-path
+        span = self.spans.span
+        with span("engine.control"):
+            self._apply_control()
+            self._flush_slot_updates()   # deactivations BEFORE slots reuse
         self._admit()
         forked = self._apply_forks()  # parents admitted above fork NOW
         chunked = False
         if self._prefills:
-            chunked = self._run_prefill_chunks()  # budgeted, between ticks
+            with span("engine.prefill_chunks"):
+                chunked = self._run_prefill_chunks()  # budgeted
         if self.paged:
-            self._ensure_blocks()    # may COW shared blocks or preempt
-        self._flush_slot_updates()
+            with span("engine.blocks"):
+                self._ensure_blocks()    # may COW shared blocks or preempt
+        with span("engine.control"):
+            self._flush_slot_updates()
         active = [i for i, r in enumerate(self.slot_req)
                   if r is not None and not r.hold
                   and i not in self._prefills]
         if not active:
             return forked or chunked
         self.ticks += 1
+        self.slot_ticks += len(active)
         self.peak_active = max(self.peak_active, len(active))
         injected = [i for i in active if self.slot_req[i].uniforms is not None]
         if injected and len(injected) != len(active):
             raise ValueError("cannot mix uniform-injected and RNG-sampled "
                              "requests in one tick")
         if injected:
-            u = np.full((self.slots, self.cfg.vocab_size), 0.5, np.float32)
-            for i in active:
-                r = self.slot_req[i]
-                u[i] = r.uniforms[len(r.out_tokens)]
-            self.cache, self._state, packed, self.last_logits = _tick_u_jit(
-                self.params, self.cache, self._state,
-                jax.device_put(u, self.device),
-                cfg=self.cfg, kn=self._kn)
+            with span("engine.uniforms"):
+                u = np.full((self.slots, self.cfg.vocab_size), 0.5,
+                            np.float32)
+                for i in active:
+                    r = self.slot_req[i]
+                    u[i] = r.uniforms[len(r.out_tokens)]
+                u = jax.device_put(u, self.device)
+            with span("engine.tick.dispatch", program=_tick_u_jit.__name__):
+                (self.cache, self._state, packed,
+                 self.last_logits) = _tick_u_jit(
+                    self.params, self.cache, self._state, u,
+                    cfg=self.cfg, kn=self._kn)
         else:
-            (self.cache, self._state, packed, self.last_logits,
-             self._rng) = _tick_rng_jit(self.params, self.cache, self._state,
-                                        self._rng, cfg=self.cfg, kn=self._kn)
+            with span("engine.tick.dispatch", program=_tick_rng_jit.__name__):
+                (self.cache, self._state, packed, self.last_logits,
+                 self._rng) = _tick_rng_jit(
+                    self.params, self.cache, self._state, self._rng,
+                    cfg=self.cfg, kn=self._kn)
         self._slot_pos[active] += 1  # mirror the in-graph step advance
-        arr = self._fetch(packed)    # ONE sync per tick
-        for slot in active:
-            self._apply_host(self.slot_req[slot], slot, arr[:, slot])
+        with span("engine.tick.wait"):
+            arr = self._fetch(packed)    # ONE sync per tick
+        with span("engine.apply_host"):
+            emitted = 0
+            for slot in active:
+                emitted += self._apply_host(self.slot_req[slot], slot,
+                                            arr[:, slot])
+            self.events_tick += emitted
         return True
 
     def run(self, max_ticks: int = 10_000) -> List[Request]:  # repro-lint: engine-thread-only
@@ -2035,160 +2140,3 @@ class BatchedEngine:
             ticks += 1
         return self.completed
 
-
-# ===========================================================================
-# Reference engine — the original host-loop implementation, kept as the
-# before/after baseline for ``benchmarks/run.py serve``.  One vmapped
-# single-slot decode per tick, per-slot host-side Python sampling, and a
-# host round-trip per slot per tick.  (Retains the pre-parity-fix max-age
-# semantics: the event crossing max_age is still emitted.)
-# ===========================================================================
-class ReferenceEngine:
-    """Seed slot engine: vmap-of-single-slot decode + host-side sampling."""
-
-    def __init__(self, params, cfg: ModelConfig, *, slots: int = 8,
-                 max_context: int = 512, temperature: float = 1.0,
-                 seed: int = 0):
-        self.params = params
-        self.cfg = cfg
-        self.slots = slots
-        self.max_context = max_context
-        self.temperature = temperature
-        self.is_delphi = cfg.age_encoding
-        self.rng = jax.random.PRNGKey(seed)
-
-        self.cache = make_decode_cache(params, cfg, slots, max_context)
-        self.slot_req: List[Optional[Request]] = [None] * slots
-        self.slot_step = np.zeros(slots, np.int64)       # abs position per slot
-        self.slot_age = np.zeros(slots, np.float64)
-        self.slot_last = np.zeros(slots, np.int32)       # last emitted token
-        self.pending: List[Request] = []
-        self.completed: List[Request] = []
-        self._build_jits()
-
-    def _build_jits(self):
-        cfg = self.cfg
-
-        @jax.jit
-        def _prefill(params, tokens, ages):
-            batch = {"tokens": tokens}
-            if cfg.age_encoding:
-                batch["ages"] = ages
-            out = forward(params, cfg, batch, mode="prefill",
-                          cache_width=self.max_context)
-            return out["cache"], out["logits"][:, -1]
-
-        @jax.jit
-        def _step(params, cache, tokens, ages, steps):
-            # per-slot absolute steps differ: vmap the single-slot decode
-            def one(c, t, a, s):
-                c = jax.tree_util.tree_map(lambda x: x[:, None], c)
-                b = {"tokens": t[None]}
-                if cfg.age_encoding:
-                    b["ages"] = a[None]
-                d = decode_step(params, cfg, c, b, s)
-                nc = jax.tree_util.tree_map(lambda x: jnp.squeeze(x, 1),
-                                            d["cache"])
-                return nc, d["logits"][0, 0]
-            caches, logits = jax.vmap(
-                one, in_axes=(_batch_axes(cache), 0, 0, 0),
-                out_axes=(_batch_axes(cache), 0))(cache, tokens, ages, steps)
-            return caches, logits
-
-        @functools.partial(jax.jit, donate_argnums=(0,))
-        def _insert(cache, slot_cache, slot):
-            return jax.tree_util.tree_map(
-                lambda buf, new: _insert_slot(buf, new, slot), cache, slot_cache)
-
-        self._prefill = _prefill
-        self._step = _step
-        self._insert = _insert
-
-    def submit(self, req: Request):
-        req.out_tokens, req.out_ages = [], []
-        self.pending.append(req)
-
-    def _admit(self):
-        for slot in range(self.slots):
-            if self.slot_req[slot] is None and self.pending:
-                req = self.pending.pop(0)
-                S = len(req.tokens)
-                tokens = jnp.asarray(req.tokens, jnp.int32)[None]
-                ages = (jnp.asarray(req.ages, jnp.float32)[None]
-                        if req.ages is not None else jnp.zeros((1, S), jnp.float32))
-                slot_cache, last_logits = self._prefill(self.params, tokens, ages)
-                # drop the leading batch dim of 1, insert at `slot`
-                slot_cache = _strip_batch_one(slot_cache)
-                self.cache = self._insert(self.cache, slot_cache, slot)
-                self.slot_req[slot] = req
-                self.slot_step[slot] = S
-                self.slot_age[slot] = float(req.ages[-1]) if req.ages is not None else 0.0
-                # sample the first token from the prefill logits
-                self._emit(slot, np.asarray(last_logits[0]))
-
-    def _emit(self, slot: int, logits: np.ndarray):
-        req = self.slot_req[slot]
-        cfg = self.cfg
-        self.rng, k = jax.random.split(self.rng)
-        if self.is_delphi:
-            u = np.asarray(jax.random.uniform(k, (cfg.vocab_size,)))
-            evt, tmin = sample_next_event(jnp.asarray(logits), jnp.asarray(u))
-            evt, tmin = int(evt), float(tmin)
-            self.slot_age[slot] += tmin
-            done = (evt == cfg.death_token or self.slot_age[slot] > cfg.max_age
-                    or len(req.out_tokens) + 1 >= req.max_new)
-            req.out_tokens.append(evt)
-            req.out_ages.append(self.slot_age[slot])
-        else:
-            lg = logits / max(self.temperature, 1e-6)
-            evt = int(jax.random.categorical(k, jnp.asarray(lg)))
-            done = len(req.out_tokens) + 1 >= req.max_new
-            req.out_tokens.append(evt)
-        self.slot_last[slot] = evt
-        if done or self.slot_step[slot] + 1 >= self.max_context:
-            req.done = True
-            self.completed.append(req)
-            self.slot_req[slot] = None
-
-    def step(self):
-        """One engine tick: admit pending, decode all active slots, sample."""
-        self._admit()
-        active = [i for i in range(self.slots) if self.slot_req[i] is not None]
-        if not active:
-            return False
-        tokens = jnp.asarray(self.slot_last[:, None], jnp.int32)
-        ages = jnp.asarray(self.slot_age[:, None], jnp.float32)
-        steps = jnp.asarray(self.slot_step, jnp.int32)
-        self.cache, logits = self._step(self.params, self.cache, tokens, ages, steps)
-        logits = np.asarray(logits)
-        for slot in active:
-            self.slot_step[slot] += 1
-            self._emit(slot, logits[slot])
-        return True
-
-    def run(self, max_ticks: int = 10_000) -> List[Request]:
-        ticks = 0
-        while (self.pending or any(r is not None for r in self.slot_req)) \
-                and ticks < max_ticks:
-            self.step()
-            ticks += 1
-        return self.completed
-
-
-# -- tree helpers ------------------------------------------------------------
-def _batch_axes(cache):
-    """vmap in_axes pytree: batch axis position per cache leaf.
-
-    Cache leaves are stacked (L, B, ...) so the batch axis is 1."""
-    return jax.tree_util.tree_map(lambda _: 1, cache)
-
-
-def _strip_batch_one(cache):
-    """(L, 1, ...) -> (L, ...) for insertion along the slot axis."""
-    return jax.tree_util.tree_map(lambda a: jnp.squeeze(a, axis=1), cache)
-
-
-def _insert_slot(buf, new, slot):
-    """buf (L, B, ...), new (L, ...) -> write at batch index `slot`."""
-    return jax.lax.dynamic_update_index_in_dim(buf, new.astype(buf.dtype),
-                                               slot, 1)

@@ -285,8 +285,7 @@ class InferenceServer:
 class _Handler(BaseHTTPRequestHandler):
     """HTTP/1.1 with keep-alive: JSON responses carry ``Content-Length`` so
     one connection serves many sequential requests (``RemoteBackend`` holds
-    a persistent connection per backend — the req/s lever
-    ``benchmarks/run.py http`` measures).  SSE responses are the exception:
+    a persistent connection per backend).  SSE responses are the exception:
     they are close-delimited (no chunked encoding on the stdlib server), so
     ``/v1/stream`` sends ``Connection: close`` and drops the connection."""
     server_version = SERVER_NAME

@@ -375,8 +375,8 @@ def test_serve_artifact_backend(setup, tmp_path):
 # HTTP/1.1 keep-alive connection reuse
 # ---------------------------------------------------------------------------
 def test_keep_alive_reuses_one_connection(setup):
-    """Sequential JSON calls ride ONE persistent connection (the req/s
-    lever `benchmarks/run.py http` measures); SSE gets its own socket."""
+    """Sequential JSON calls ride ONE persistent connection; SSE gets its
+    own socket."""
     _, cfg, server = setup
     remote = RemoteBackend(server.address)
     assert remote.connections_opened == 1       # the manifest handshake
