@@ -58,8 +58,10 @@ class PagedCache(NamedTuple):
     indirection: with ``W = blocks_per_slot * block_size``, the token at
     absolute position ``p`` of slot ``b`` lives at
     ``pool[table[b, (p % W) // block_size], p % block_size]`` — the same
-    ``p % W`` ring slot the dense cache uses.  ``paged_gather_layer``
-    therefore reconstructs a bit-identical ``LayerCache`` view, which is
+    ``p % W`` ring slot the dense cache uses: ring positions
+    ``[jb * block_size, (jb + 1) * block_size)`` of slot ``b`` are exactly
+    pool block ``table[b, jb]``.  ``paged_gather_layer`` therefore
+    reconstructs a bit-identical ``LayerCache`` view, which is
     what makes the paged engine's trajectories bit-equal to the ring
     engine's under injected uniforms.
     """
@@ -70,13 +72,13 @@ class PagedCache(NamedTuple):
 
 
 class PagedLayerView(NamedTuple):
-    """One layer's slice of a :class:`PagedCache` (the shared ``pos`` /
-    ``table`` plus that layer's pool planes) — what the decode layer scan
-    hands to :func:`decode_attention`."""
+    """One layer's pool planes plus the tick's layer-independent ring index
+    (:func:`paged_ring_index`, built once outside the layer scan) — what the
+    decode layer scan hands to :func:`decode_attention`."""
     k: jax.Array          # (num_blocks, Hkv, block_size, hd)
     v: jax.Array
-    pos: jax.Array        # (num_blocks, block_size)
-    table: jax.Array      # (B, blocks_per_slot)
+    blocks: jax.Array     # (B, blocks_per_slot) pool ids, -1 -> trash block 0
+    pos: jax.Array        # (B, W) int32 absolute position per ring slot
 
 
 # ---------------------------------------------------------------------------
@@ -221,28 +223,44 @@ def chunked_attention(q, k, v, q_pos, k_pos, *, causal: bool,
 
 
 @jax.named_scope("paged_gather")
+def paged_ring_index(pos, table):
+    """The layer-independent half of the paged ring view, built once a tick.
+
+    pos: the pool's ``(num_blocks, block_size)`` positions; table:
+    ``(B, blocks_per_slot)``.  Returns ``(blocks, ring_pos)``: the table
+    with unallocated entries (-1) pointed at the trash block 0, and the
+    ``(B, W)`` absolute position of every ring slot, -1 where the table
+    entry is unallocated — exactly like an empty ring slot.
+    """
+    B = table.shape[0]
+    blocks = jnp.maximum(table, 0)
+    ring_pos = jnp.where(table[:, :, None] >= 0, pos[blocks], -1)
+    return blocks, ring_pos.reshape(B, -1).astype(jnp.int32)
+
+
+@jax.named_scope("paged_gather")
 def paged_gather_layer(view: PagedLayerView) -> LayerCache:
     """Reconstruct the dense ring view of one layer's paged cache.
 
-    Ring slot ``j`` of slot ``b`` is ``pool[table[b, j // bs], j % bs]``;
-    unallocated table entries gather (masked) garbage from the trash block
-    and carry ``pos = -1``, exactly like an empty ring slot — so the result
-    feeds the unchanged :func:`decode_attention` math and the paged decode
-    is bit-identical to the ring decode.  (The dense gather is a transient;
-    the fused no-materialization read lives in
+    Ring slots ``[jb * bs, (jb + 1) * bs)`` of slot ``b`` are the whole pool
+    block ``blocks[b, jb]``, so K and V are gathered one block per table
+    entry and laid out ``(B, Hkv, W, hd)``; unallocated entries read
+    (masked) garbage from the trash block and carry ``pos = -1`` — so the
+    result feeds the unchanged :func:`decode_attention` math and the paged
+    decode is bit-identical to the ring decode.  Each block is read as
+    ``Hkv`` rows of ``bs * hd``: on the TPU that view gathers and lays out
+    cheaper than ``(Hkv, bs, hd)``, whose narrow minor axes XLA pads.  (The
+    dense gather is a transient; the fused no-materialization read lives in
     ``repro.kernels.paged_decode_attention``.)
     """
-    B, nbs = view.table.shape
-    bs = view.k.shape[2]
-    W = nbs * bs
-    j = jnp.arange(W)
-    blk = view.table[:, j // bs]                       # (B, W) pool ids
-    off = jnp.broadcast_to(j % bs, (B, W))
-    safe = jnp.maximum(blk, 0)
-    k = view.k[safe, :, off, :].transpose(0, 2, 1, 3)  # (B, Hkv, W, hd)
-    v = view.v[safe, :, off, :].transpose(0, 2, 1, 3)
-    pos = jnp.where(blk >= 0, view.pos[safe, off], -1).astype(jnp.int32)
-    return LayerCache(k=k, v=v, pos=pos)
+    B, nbs = view.blocks.shape
+    NB, Hkv, bs, hd = view.k.shape
+
+    def ring(pool):          # (B, nbs, Hkv, bs * hd) -> (B, Hkv, W, hd)
+        rows = pool.reshape(NB, Hkv, bs * hd)[view.blocks]
+        return rows.transpose(0, 2, 1, 3).reshape(B, Hkv, nbs * bs, hd)
+
+    return LayerCache(k=ring(view.k), v=ring(view.v), pos=view.pos)
 
 
 def paged_write_stacked(caches: PagedCache, k_news, v_news,

@@ -276,16 +276,18 @@ def _transformer_stack(layers, x, positions, cfg, *, mode, memory=None,
     # round-tripping the full cache through scan temporaries)
     if isinstance(caches, attn_lib.PagedCache):
         # paged decode: the scan carries each layer's pool planes; the
-        # shared block table / positions are closed over (they have no
-        # layer axis).  decode_attention dispatches on the PagedLayerView.
+        # block ids and ring positions have no layer axis, so they are
+        # built once here and closed over.  decode_attention dispatches on
+        # the PagedLayerView.
         if has_cross:
             raise ValueError("paged KV cache does not support cross-"
                              "attention stacks")
         pc = caches
+        blocks, ring_pos = attn_lib.paged_ring_index(pc.pos, pc.table)
 
         def body(h, xs):
             lp, kl, vl = xs
-            view = attn_lib.PagedLayerView(kl, vl, pc.pos, pc.table)
+            view = attn_lib.PagedLayerView(kl, vl, blocks, ring_pos)
             h, kv, _, _ = transformer_layer(
                 lp, h, positions, cfg, mode="decode", cache=view, step=step,
                 moe_impl=moe_impl, defer_write=True)

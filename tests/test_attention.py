@@ -1,5 +1,8 @@
 """Attention: chunked-flash vs naive, ring caches, GQA, sliding window,
 and the paged (block pool + block table) twin of the ring cache."""
+import re
+from typing import NamedTuple
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -10,7 +13,8 @@ from repro.models.attention import (LayerCache, PagedCache, PagedLayerView,
                                     cache_from_prefill, cache_write,
                                     cache_write_stacked, chunked_attention,
                                     decode_attention, empty_cache,
-                                    empty_paged_cache, paged_gather_layer)
+                                    empty_paged_cache, paged_gather_layer,
+                                    paged_ring_index)
 
 
 def _mk(key, B, Hq, Hkv, S, hd):
@@ -175,7 +179,40 @@ def test_mask_padded_positions_under_wrap(key):
 # ---------------------------------------------------------------------------
 # Paged cache: pool + block-table twin of the ring
 # ---------------------------------------------------------------------------
-def _ring_to_paged(ring: LayerCache, bs: int):
+class _Pool(NamedTuple):
+    """One layer's paged cache: pool planes, pool positions, block table."""
+    k: jax.Array          # (num_blocks, Hkv, block_size, hd)
+    v: jax.Array
+    pos: jax.Array        # (num_blocks, block_size)
+    table: jax.Array      # (B, blocks_per_slot)
+
+
+def _view(k, v, pos, table) -> PagedLayerView:
+    """The layer view the decode scan builds from one layer's pool."""
+    return PagedLayerView(k, v, *paged_ring_index(pos, table))
+
+
+def _per_position_gather(k, v, pos, table) -> LayerCache:
+    """Test oracle: the ring view gathered one position at a time.
+
+    Ring slot ``j`` of slot ``b`` is ``pool[table[b, j // bs], j % bs]``;
+    unallocated table entries gather from the trash block 0 and carry
+    ``pos = -1``.
+    """
+    B, nbs = table.shape
+    bs = k.shape[2]
+    W = nbs * bs
+    j = jnp.arange(W)
+    blk = table[:, j // bs]                       # (B, W) pool ids
+    off = jnp.broadcast_to(j % bs, (B, W))
+    safe = jnp.maximum(blk, 0)
+    rk = k[safe, :, off, :].transpose(0, 2, 1, 3)  # (B, Hkv, W, hd)
+    rv = v[safe, :, off, :].transpose(0, 2, 1, 3)
+    rpos = jnp.where(blk >= 0, pos[safe, off], -1).astype(jnp.int32)
+    return LayerCache(k=rk, v=rv, pos=rpos)
+
+
+def _ring_to_paged(ring: LayerCache, bs: int) -> _Pool:
     """Pack a ring LayerCache into an equivalent single-layer paged pool."""
     B, Hkv, W, hd = ring.k.shape
     nbs = W // bs
@@ -195,8 +232,8 @@ def _ring_to_paged(ring: LayerCache, bs: int):
             pool_v[nxt] = rv[b, :, jb * bs:(jb + 1) * bs]
             pool_pos[nxt] = rp[b, jb * bs:(jb + 1) * bs]
             nxt += 1
-    return PagedLayerView(jnp.asarray(pool_k), jnp.asarray(pool_v),
-                          jnp.asarray(pool_pos), jnp.asarray(table))
+    return _Pool(jnp.asarray(pool_k), jnp.asarray(pool_v),
+                 jnp.asarray(pool_pos), jnp.asarray(table))
 
 
 def test_paged_gather_reconstructs_ring_bitwise(key):
@@ -206,7 +243,7 @@ def test_paged_gather_reconstructs_ring_bitwise(key):
     v = jax.random.normal(ks[1], (B, S, Hkv, hd))
     pos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
     ring = cache_from_prefill(k, v, pos, W)
-    g = paged_gather_layer(_ring_to_paged(ring, bs))
+    g = paged_gather_layer(_view(*_ring_to_paged(ring, bs)))
     np.testing.assert_array_equal(g.pos, ring.pos)
     valid = np.asarray(ring.pos) >= 0
     np.testing.assert_array_equal(
@@ -227,7 +264,7 @@ def test_paged_decode_bit_identical_to_ring(key):
     vn = jax.random.normal(ks[4], (B, 1, Hkv, hd))
     pos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
     ring = cache_from_prefill(k, v, pos, W)
-    view = _ring_to_paged(ring, bs)
+    view = _view(*_ring_to_paged(ring, bs))
     step = jnp.full((B,), S, jnp.int32)
     for window in (None, 6):
         o_r = decode_attention(q, ring, step, window=window, q_per_kv=2,
@@ -246,21 +283,151 @@ def test_paged_write_stacked_matches_ring_write(key):
     v = jax.random.normal(ks[1], (B, 6, Hkv, hd))
     pos = jnp.broadcast_to(jnp.arange(6, dtype=jnp.int32)[None], (B, 6))
     ring = cache_from_prefill(k, v, pos, W)
-    view = _ring_to_paged(ring, bs)
-    pc = PagedCache(k=jnp.stack([view.k] * L), v=jnp.stack([view.v] * L),
-                    pos=view.pos, table=view.table)
+    pool = _ring_to_paged(ring, bs)
+    pc = PagedCache(k=jnp.stack([pool.k] * L), v=jnp.stack([pool.v] * L),
+                    pos=pool.pos, table=pool.table)
     ring_st = jax.tree_util.tree_map(lambda a: jnp.stack([a] * L), ring)
     kn = jax.random.normal(ks[2], (L, B, 1, Hkv, hd))
     step = jnp.asarray([6, 7], jnp.int32)
     r2 = cache_write_stacked(ring_st, kn, kn, step)
     p2 = cache_write_stacked(pc, kn, kn, step)
     assert isinstance(p2, PagedCache)
-    g = paged_gather_layer(PagedLayerView(p2.k[0], p2.v[0], p2.pos, p2.table))
+    g = paged_gather_layer(_view(p2.k[0], p2.v[0], p2.pos, p2.table))
     np.testing.assert_array_equal(g.pos, r2.pos[0])
     valid = np.asarray(r2.pos[0]) >= 0
     np.testing.assert_array_equal(
         np.asarray(g.k).transpose(0, 2, 1, 3)[valid],
         np.asarray(r2.k[0]).transpose(0, 2, 1, 3)[valid])
+
+
+_GATHER_CASES = {             # Hkv, hd, num_blocks, slots, blocks per slot
+    "delphi_heads": (12, 10, 65, 8, 4),
+    "gqa_danube_heads": (8, 80, 5, 2, 2),
+    "unallocated": (12, 10, 65, 8, 4),
+    "fork_shared": (12, 10, 65, 8, 4),
+}
+
+
+def _gather_case(case: str, key) -> _Pool:
+    bs, bf16 = 16, jnp.bfloat16
+    ks = jax.random.split(key, 2)
+    if case == "wrapped_ring":
+        B, Hkv, hd, S, W = 3, 8, 80, 45, 32          # S > W: the ring wrapped
+        k = jax.random.normal(ks[0], (B, S, Hkv, hd))
+        v = jax.random.normal(ks[1], (B, S, Hkv, hd))
+        pos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
+        pool = _ring_to_paged(cache_from_prefill(k, v, pos, W), bs)
+        return pool._replace(k=pool.k.astype(bf16), v=pool.v.astype(bf16))
+    Hkv, hd, NB, B, nbs = _GATHER_CASES[case]
+    rng = np.random.default_rng(7)
+    table = rng.permutation(np.arange(1, NB))[:B * nbs].reshape(B, nbs)
+    if case == "unallocated":
+        table[rng.random((B, nbs)) < 0.4] = -1
+        table[B - 1] = -1                            # an idle slot
+    if case == "fork_shared":
+        table[1, :2] = table[0, :2]        # a fork shares its parent's prefix
+        table[2] = table[0]
+    pos = rng.integers(0, 4 * NB * bs, (NB, bs))
+    pos[rng.random((NB, bs)) < 0.2] = -1             # empty positions
+    return _Pool(jax.random.normal(ks[0], (NB, Hkv, bs, hd), bf16),
+                 jax.random.normal(ks[1], (NB, Hkv, bs, hd), bf16),
+                 jnp.asarray(pos, jnp.int32), jnp.asarray(table, jnp.int32))
+
+
+@pytest.mark.parametrize("case", [*_GATHER_CASES, "wrapped_ring"])
+def test_block_gather_matches_per_position_oracle(key, case):
+    """The served whole-block gather equals the per-position oracle bit for
+    bit in k, v and pos: trash-block reads of unallocated entries, shared
+    (forked) blocks and wrapped rings included."""
+    pool = _gather_case(case, key)
+    got = paged_gather_layer(_view(*pool))
+    want = _per_position_gather(*pool)
+    for g, w in zip(got, want):
+        assert g.shape == w.shape and g.dtype == w.dtype
+        g, w = np.asarray(g), np.asarray(w)
+        if g.dtype != np.int32:
+            g, w = g.view(np.uint16), w.view(np.uint16)
+        np.testing.assert_array_equal(g, w)
+
+
+def _hlo_computations(text: str):
+    """HLO text -> {computation: {instruction: (shape, opcode, operands,
+    attributes)}}."""
+    comps, cur = {}, None
+    inst = re.compile(r"(?:ROOT )?%?([\w.\-]+) = (.*?) ([a-z][\w\-]*)"
+                      r"\((.*?)\)(.*)")
+    for line in text.splitlines():
+        if line and not line[0].isspace() and line.endswith("{"):
+            cur = comps.setdefault(line.split()[-2].lstrip("%").split("(")[0],
+                                   {})
+        elif line == "}":
+            cur = None
+        elif cur is not None and (m := inst.match(line.strip())):
+            name, shape, op, args, attrs = m.groups()
+            cur[name] = (shape, op, [a.lstrip("%") for a in args.split(", ")],
+                         attrs)
+    return comps
+
+
+def _called(comps, roots):
+    """Every computation reachable from ``roots`` through calls."""
+    seen, todo = set(), list(roots)
+    while todo:
+        c = todo.pop()
+        if c in seen:
+            continue
+        seen.add(c)
+        for _, _, _, attrs in comps[c].values():
+            todo += re.findall(r"(?:to_apply|calls|body|condition)=%?"
+                               r"([\w.\-]+)", attrs)
+            for group in re.findall(r"branch_computations=\{([^}]*)\}", attrs):
+                todo += [g.strip().lstrip("%") for g in group.split(",")]
+    return seen
+
+
+def test_paged_decode_scan_gathers_whole_blocks():
+    """Structural guard on the lowered paged decode_step: inside the layer
+    scan no gather reads the pool's (num_blocks, block_size) position plane
+    or the (slots, blocks_per_slot) table, and every K/V gather collapses
+    the pool's block axis only — one whole block per table entry."""
+    from repro.configs import get_config
+    from repro.models import decode_step, init_params, make_paged_decode_cache
+    cfg = get_config("delphi-2m", reduced=True)
+    B, ctx, bs = 4, 64, 16
+    NB, nbs = B * ctx // bs + 1, ctx // bs
+    Hkv, hd = cfg.n_kv_heads, cfg.head_dim
+    params = jax.eval_shape(lambda k: init_params(cfg, k),
+                            jax.random.PRNGKey(0))
+    cache = jax.eval_shape(lambda: make_paged_decode_cache(
+        cfg, B, ctx, num_blocks=NB, block_size=bs))
+    batch = {"tokens": jax.ShapeDtypeStruct((B, 1), jnp.int32),
+             "ages": jax.ShapeDtypeStruct((B, 1), jnp.float32)}
+    step = jax.ShapeDtypeStruct((B,), jnp.int32)
+    text = jax.jit(lambda p, c, b, s: decode_step(p, cfg, c, b, s)).lower(
+        params, cache, batch, step).compiler_ir("hlo").as_hlo_text()
+    plane = {"bfloat16": "bf16", "float32": "f32"}[
+        str(cache["self"].k.dtype)]
+    comps = _hlo_computations(text)
+    bodies = [re.search(r"body=%?([\w.\-]+)", i[3]).group(1)
+              for c in comps.values() for i in c.values() if i[1] == "while"]
+    assert bodies, "no layer scan in the paged decode program"
+    kv_gathers = 0
+    for c in _called(comps, bodies):
+        for _, op, args, attrs in comps[c].values():
+            if op != "gather":
+                continue
+            operand = comps[c][args[0]][0]
+            dims = operand[operand.index("[") + 1:operand.index("]")]
+            assert dims not in (f"{NB},{bs}", f"{B},{nbs}"), (
+                f"per-position index gather in the layer scan: {operand}")
+            lead, *rest = dims.split(",")
+            if int(lead) == NB and operand.startswith(plane):
+                # a K/V plane, however its block is viewed: whole blocks
+                kv_gathers += 1
+                assert np.prod([int(d) for d in rest]) == Hkv * bs * hd
+                assert "collapsed_slice_dims={0}," in attrs, attrs
+                assert f"slice_sizes={{1,{','.join(rest)}}}" in attrs, attrs
+    assert kv_gathers == 2, kv_gathers       # K and V, once in the body
 
 
 def test_empty_paged_cache_shapes_and_validation():
