@@ -29,6 +29,7 @@ _REGISTRY: Dict[str, str] = {
     "h2o-danube-1.8b": "h2o_danube_1_8b",
     "olmoe-1b-7b": "olmoe_1b_7b",
     "deepseek-7b": "deepseek_7b",
+    "moonlight-16b-a3b": "moonlight_16b_a3b",
 }
 
 # The 10 architectures assigned to this paper (delphi-* are the paper's own).

@@ -44,11 +44,27 @@ class ModelConfig:
     sliding_window: Optional[int] = None   # SWA window (tokens); None = full attn
 
     # MoE ------------------------------------------------------------------
-    n_experts: int = 0                 # routed experts (0 = dense MLP)
+    n_experts: int = 0                 # routed experts held (0 = dense MLP)
     top_k: int = 0
     n_shared_experts: int = 0          # always-on experts (qwen2-moe style)
     moe_d_ff: int = 0                  # per-expert hidden dim
     router_aux_coef: float = 0.01
+    # expert parallelism: the router scores ``n_router_experts`` (0 = the
+    # ``n_experts`` held) and this layer holds experts
+    # [expert_offset, expert_offset + n_experts) of them
+    n_router_experts: int = 0
+    expert_offset: int = 0
+    # softmax (qwen/olmoe) | sigmoid (DeepSeek-V3: a selection-only bias,
+    # chosen scores normalised and times ``routed_scaling``)
+    router_score: str = "softmax"
+    routed_scaling: float = 1.0
+    first_dense_layers: int = 0        # leading dense-MLP layers (MoE stacks)
+
+    # latent attention (MLA, DeepSeek-V2/V3): kv_lora_rank > 0 ------------------
+    kv_lora_rank: int = 0              # latent width c; the cache holds [c | k_pe]
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
 
     # SSM / Mamba2 (SSD) -----------------------------------------------------
     ssm_state: int = 0                 # N — state size per head
@@ -101,7 +117,9 @@ class ModelConfig:
         if self.n_heads:
             assert self.n_heads % max(self.n_kv_heads, 1) == 0, "GQA requires n_heads % n_kv_heads == 0"
         if self.n_experts:
-            assert 0 < self.top_k <= self.n_experts
+            assert 0 < self.top_k <= self.router_experts
+            assert 0 <= self.expert_offset <= self.router_experts - self.n_experts
+        assert self.router_score in ("softmax", "sigmoid"), self.router_score
 
     # convenience -------------------------------------------------------------
     @property
@@ -119,6 +137,15 @@ class ModelConfig:
     @property
     def ssm_n_heads(self) -> int:
         return self.d_inner // self.ssm_head_dim
+
+    @property
+    def router_experts(self) -> int:
+        """Experts the router scores: all of them, held here or not."""
+        return self.n_router_experts or self.n_experts
+
+    @property
+    def is_mla(self) -> bool:
+        return self.kv_lora_rank > 0
 
     @property
     def q_per_kv(self) -> int:
@@ -149,7 +176,11 @@ class ModelConfig:
             kw["n_kv_heads"] = max(1, 4 // min(self.q_per_kv, 4))
         if self.n_experts:
             kw.update(n_experts=4, top_k=min(self.top_k, 2), moe_d_ff=128,
-                      n_shared_experts=min(self.n_shared_experts, 1))
+                      n_shared_experts=min(self.n_shared_experts, 1),
+                      n_router_experts=0, expert_offset=0)
+        if self.kv_lora_rank:   # four different widths: a slice of one
+            kw.update(kv_lora_rank=48, qk_nope_head_dim=32,  # for another
+                      qk_rope_head_dim=16, v_head_dim=24)    # shows
         if self.ssm_state:
             kw.update(ssm_state=16, ssm_head_dim=32, ssm_chunk=32)
         if self.attn_every:

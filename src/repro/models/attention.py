@@ -25,7 +25,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.models.layers import apply_rope, param_dtype
+from repro.models.layers import apply_norm, apply_rope, param_dtype
 
 NEG_INF = -1e30
 
@@ -50,6 +50,9 @@ class PagedCache(NamedTuple):
       k, v  : (L, num_blocks, Hkv, block_size, hd) — the shared pool.
               Block 0 is the **trash block**: writes of slots with no
               allocated destination land there and are never read back.
+              Latent attention keeps one ``[c | k_pe]`` row a token in the
+              k plane (Hkv 1) and an empty v plane (width 0): the same
+              blocks, gather and scatter (:func:`cache_rows`).
       pos   : (num_blocks, block_size) int32 absolute positions, -1 = empty.
               Layer-independent (every layer writes the same positions).
       table : (B, blocks_per_slot) int32 pool block ids, -1 = unallocated.
@@ -85,6 +88,8 @@ class PagedLayerView(NamedTuple):
 # Parameters
 # ---------------------------------------------------------------------------
 def init_attention(key, cfg: ModelConfig, cross: bool = False):
+    if cfg.is_mla and not cross:
+        return init_mla(key, cfg)
     pdt = param_dtype(cfg)
     d, hq, hkv, hd = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     k1, k2, k3, k4 = jax.random.split(key, 4)
@@ -100,6 +105,27 @@ def init_attention(key, cfg: ModelConfig, cross: bool = False):
         p["bk"] = jnp.zeros((hkv, hd), pdt)
         p["bv"] = jnp.zeros((hkv, hd), pdt)
     return p
+
+
+def init_mla(key, cfg: ModelConfig):
+    """Latent attention without query compression (DeepSeek-V2/V3 with
+    ``q_lora_rank`` null): ``wq`` gives each head [q_nope | q_pe];
+    ``wkv_a`` gives the latent c (normed by ``kv_norm``) and the shared
+    k_pe; ``wkv_b`` expands c into each head's [k_nope | v]."""
+    pdt = param_dtype(cfg)
+    d, H = cfg.d_model, cfg.n_heads
+    r, dn, dr, dv = (cfg.kv_lora_rank, cfg.qk_nope_head_dim,
+                     cfg.qk_rope_head_dim, cfg.v_head_dim)
+    k1, k2, k3, k4 = jax.random.split(key, 4)
+    s = d ** -0.5
+    return {
+        "wq": (jax.random.normal(k1, (d, H, dn + dr)) * s).astype(pdt),
+        "wkv_a": (jax.random.normal(k2, (d, r + dr)) * s).astype(pdt),
+        "kv_norm": {"scale": jnp.ones((r,), pdt)},
+        "wkv_b": (jax.random.normal(k3, (r, H, dn + dv))
+                  * r ** -0.5).astype(pdt),
+        "wo": (jax.random.normal(k4, (H, dv, d)) * (H * dv) ** -0.5).astype(pdt),
+    }
 
 
 def _project_qkv(params, xq, xkv, cfg: ModelConfig):
@@ -131,12 +157,15 @@ def chunked_attention(q, k, v, q_pos, k_pos, *, causal: bool,
                       window: Optional[int], q_block: int = 512,
                       kv_block: int = 512, q_per_kv: int = 1,
                       unroll: bool = False):
-    """q: (B, Sq, Hq, hd); k, v: (B, Skv, Hkv, hd); *_pos int32 (B, S*) or (S*,).
+    """q, k: (B, S*, H*, hd); v: (B, Skv, Hkv, dv); *_pos int32 (B, S*) or
+    (S*,).
 
-    Invalid KV slots are marked with k_pos < 0.  Returns (B, Sq, Hq, hd).
+    Invalid KV slots are marked with k_pos < 0.  Returns (B, Sq, Hq, dv):
+    values may be narrower than queries and keys (latent attention).
     """
     B, Sq, Hq, hd = q.shape
     Skv, Hkv = k.shape[1], k.shape[2]
+    dv = v.shape[-1]
     G = q_per_kv
     assert Hq == Hkv * G
     scale = hd ** -0.5
@@ -159,7 +188,7 @@ def chunked_attention(q, k, v, q_pos, k_pos, *, causal: bool,
         s = jnp.where(valid, s, NEG_INF)
         p = jax.nn.softmax(s, axis=-1)
         o = jnp.einsum("bhgqk,bkhd->bqhgd", p.astype(v.dtype), v)
-        return o.reshape(B, Sq, Hq, hd)
+        return o.reshape(B, Sq, Hq, dv)
 
     q, _ = _pad_to(q, 1, q_block)
     q_pos_p, _ = _pad_to(q_pos, 1, q_block)
@@ -174,7 +203,7 @@ def chunked_attention(q, k, v, q_pos, k_pos, *, causal: bool,
     qb = q.reshape(B, nq, q_block, Hkv, G, hd).transpose(1, 0, 3, 4, 2, 5)
     qpb = q_pos_p.reshape(B, nq, q_block).transpose(1, 0, 2)
     kb = k.reshape(B, nk, kv_block, Hkv, hd).transpose(1, 0, 3, 2, 4)
-    vb = v.reshape(B, nk, kv_block, Hkv, hd).transpose(1, 0, 3, 2, 4)
+    vb = v.reshape(B, nk, kv_block, Hkv, dv).transpose(1, 0, 3, 2, 4)
     kpb = k_pos_p.reshape(B, nk, kv_block).transpose(1, 0, 2)
 
     @functools.partial(jax.checkpoint, policy=jax.checkpoint_policies.nothing_saveable)
@@ -198,7 +227,7 @@ def chunked_attention(q, k, v, q_pos, k_pos, *, causal: bool,
         return (o_new, m_new, l_new), None
 
     def q_step(q_i, qp_i):
-        o0 = jnp.zeros((B, Hkv, G, q_block, hd), jnp.float32)
+        o0 = jnp.zeros((B, Hkv, G, q_block, dv), jnp.float32)
         m0 = jnp.full((B, Hkv, G, q_block), NEG_INF, jnp.float32)
         l0 = jnp.zeros((B, Hkv, G, q_block), jnp.float32)
         if unroll:
@@ -218,7 +247,7 @@ def chunked_attention(q, k, v, q_pos, k_pos, *, causal: bool,
         out = jnp.stack([q_step(qb[iq], qpb[iq]) for iq in range(nq)])
     else:
         out = jax.lax.map(lambda args: q_step(*args), (qb, qpb))   # (nq, ...)
-    out = out.transpose(1, 0, 4, 2, 3, 5).reshape(B, Sq_p, Hq, hd)
+    out = out.transpose(1, 0, 4, 2, 3, 5).reshape(B, Sq_p, Hq, dv)
     return out[:, :Sq].astype(q.dtype)
 
 
@@ -254,9 +283,9 @@ def paged_gather_layer(view: PagedLayerView) -> LayerCache:
     ``repro.kernels.paged_decode_attention``.)
     """
     B, nbs = view.blocks.shape
-    NB, Hkv, bs, hd = view.k.shape
 
     def ring(pool):          # (B, nbs, Hkv, bs * hd) -> (B, Hkv, W, hd)
+        NB, Hkv, bs, hd = pool.shape
         rows = pool.reshape(NB, Hkv, bs * hd)[view.blocks]
         return rows.transpose(0, 2, 1, 3).reshape(B, Hkv, nbs * bs, hd)
 
@@ -293,7 +322,8 @@ def paged_write_stacked(caches: PagedCache, k_news, v_news,
 
 
 def decode_attention(q, cache, step, *, window: Optional[int],
-                     q_per_kv: int = 1, k_new=None, v_new=None):
+                     q_per_kv: int = 1, k_new=None, v_new=None,
+                     scale: Optional[float] = None):
     """Single-token attention against a ring cache (or paged view of one).
 
     q: (B, 1, Hq, hd) roped; cache.k/v: (B, Hkv, W, hd); step: scalar int32
@@ -306,14 +336,17 @@ def decode_attention(q, cache, step, *, window: Optional[int],
     as *read-only* and the new token is attended via an appended logit — the
     actual cache write is deferred to one post-scan scatter (keeps XLA from
     round-tripping the full cache through scan temporaries).  Ring semantics
-    are preserved by masking positions <= step - W.
+    are preserved by masking positions <= step - W.  ``scale`` defaults to
+    ``hd ** -0.5``; values may be narrower than keys (the absorbed latent
+    read), and the result takes their width.
     """
     if isinstance(cache, PagedLayerView):
         cache = paged_gather_layer(cache)
     B, _, Hq, hd = q.shape
     Hkv, W = cache.k.shape[1], cache.k.shape[2]
     G = q_per_kv
-    scale = hd ** -0.5
+    if scale is None:
+        scale = hd ** -0.5
     step = jnp.asarray(step)
     if step.ndim == 1:
         step = step.reshape(B, 1, 1, 1)   # broadcast against pos (B,1,1,W)
@@ -344,16 +377,26 @@ def decode_attention(q, cache, step, *, window: Optional[int],
     else:
         p = jax.nn.softmax(s, axis=-1)
         o = jnp.einsum("bhgw,bhwd->bhgd", p.astype(cache.v.dtype), cache.v)
-    return o.reshape(B, 1, Hq, hd)
+    return o.reshape(B, 1, Hq, cache.v.shape[-1])
 
 
 # ---------------------------------------------------------------------------
 # Cache construction / update
 # ---------------------------------------------------------------------------
+def cache_rows(cfg: ModelConfig):
+    """(heads, key width, value width) of what the cache holds per token:
+    K and V per KV head, or for latent attention one ``[c | k_pe]`` row
+    in the key plane and an empty value plane (the row holds both)."""
+    if cfg.is_mla:
+        return 1, cfg.kv_lora_rank + cfg.qk_rope_head_dim, 0
+    return cfg.n_kv_heads, cfg.head_dim, cfg.head_dim
+
+
 def empty_cache(cfg: ModelConfig, batch: int, width: int, dtype) -> LayerCache:
+    h, dk, dv = cache_rows(cfg)
     return LayerCache(
-        k=jnp.zeros((batch, cfg.n_kv_heads, width, cfg.head_dim), dtype),
-        v=jnp.zeros((batch, cfg.n_kv_heads, width, cfg.head_dim), dtype),
+        k=jnp.zeros((batch, h, width, dk), dtype),
+        v=jnp.zeros((batch, h, width, dv), dtype),
         pos=jnp.full((batch, width), -1, jnp.int32),
     )
 
@@ -369,11 +412,10 @@ def empty_paged_cache(cfg: ModelConfig, n_layers: int, num_blocks: int,
     if width % block_size != 0:
         raise ValueError(f"paged cache width {width} must be a multiple of "
                          f"block_size {block_size}")
+    h, dk, dv = cache_rows(cfg)
     return PagedCache(
-        k=jnp.zeros((n_layers, num_blocks, cfg.n_kv_heads, block_size,
-                     cfg.head_dim), dtype),
-        v=jnp.zeros((n_layers, num_blocks, cfg.n_kv_heads, block_size,
-                     cfg.head_dim), dtype),
+        k=jnp.zeros((n_layers, num_blocks, h, block_size, dk), dtype),
+        v=jnp.zeros((n_layers, num_blocks, h, block_size, dv), dtype),
         pos=jnp.full((num_blocks, block_size), -1, jnp.int32),
         table=jnp.full((slots, width // block_size), -1, jnp.int32),
     )
@@ -597,4 +639,108 @@ def attention(params, x, positions, cfg: ModelConfig, *, mode: str,
                 if positions.ndim == 1 else positions)
         new_cache = cache_from_prefill(k, v, pos2, W)
         return out, new_cache
+    return out, None
+
+
+# ---------------------------------------------------------------------------
+# Latent attention (MLA, DeepSeek-V2/V3)
+# ---------------------------------------------------------------------------
+@jax.named_scope("latent")
+def _mla_rows(params, x, positions, cfg: ModelConfig):
+    """x (B, S, d) -> the cached rows (B, S, r + dr): the normed latent
+    ``c = RMSNorm(x wkv_a[:, :r])`` and ``k_pe = RoPE(x wkv_a[:, r:])``,
+    one rotated key part shared by every head."""
+    r = cfg.kv_lora_rank
+    ckv = jnp.einsum("bsd,dr->bsr", x, params["wkv_a"].astype(x.dtype))
+    c = apply_norm(params["kv_norm"], ckv[..., :r], cfg)
+    k_pe = apply_rope(ckv[..., None, r:], positions, cfg.rope_theta)
+    return jnp.concatenate([c, k_pe[..., 0, :]], axis=-1)
+
+
+@jax.named_scope("latent")
+def _mla_expand(params, rows, cfg: ModelConfig):
+    """Rows (B, T, r + dr) -> each head's keys [c wkv_b[:, :dn] | k_pe]
+    (B, T, H, dn + dr) and values c wkv_b[:, dn:] (B, T, H, dv)."""
+    r, dn = cfg.kv_lora_rank, cfg.qk_nope_head_dim
+    kv = jnp.einsum("btr,rhk->bthk", rows[..., :r],
+                    params["wkv_b"].astype(rows.dtype))
+    k_pe = jnp.broadcast_to(rows[:, :, None, r:],
+                            kv.shape[:3] + (cfg.qk_rope_head_dim,))
+    return jnp.concatenate([kv[..., :dn], k_pe], axis=-1), kv[..., dn:]
+
+
+@jax.named_scope("mla")
+def mla_attention(params, x, positions, cfg: ModelConfig, *, mode: str,
+                  cache=None, step=None, causal: bool = True,
+                  window: Optional[int] = None,
+                  cache_width: Optional[int] = None,
+                  defer_write: bool = False, ctx_k=None, ctx_pos=None):
+    """One latent-attention layer, in the modes of :func:`attention`.
+
+    The cache holds one row ``[c | k_pe]`` per token (:func:`cache_rows`:
+    the key plane of a one-head cache; its value plane is empty).  Prefill,
+    dense and suffix modes expand rows through ``wkv_b`` into per-head keys
+    and values (suffix: the cached context rows ``ctx_k`` (B, C, 1, r + dr)
+    too).  Decode absorbs ``wkv_b``: ``q_nope wkv_b[:, :dn]^T`` joins q_pe
+    as a query over the rows, so ``n_heads`` query heads attend over one
+    head of width r + dr for scores and r for values, and ``wkv_b[:, dn:]``
+    takes the attended latent to each head's value.  Scores are scaled by
+    ``(dn + dr) ** -0.5`` in every mode.  Returns (out, what
+    :func:`attention` returns there), the new rows standing for (k, v).
+    """
+    dt = x.dtype
+    r, dn = cfg.kv_lora_rank, cfg.qk_nope_head_dim
+    win = window if window is not None else cfg.sliding_window
+    if mode == "decode":
+        st = jnp.asarray(step)
+        # (1, 1) shared position, or (B, 1) per-example engine positions
+        positions = (st.reshape(-1, 1) if st.ndim == 1
+                     else jnp.reshape(st, (1, 1)))
+    q = jnp.einsum("bsd,dhk->bshk", x, params["wq"].astype(dt))
+    q_pe = apply_rope(q[..., dn:], positions, cfg.rope_theta)
+    rows = _mla_rows(params, x, positions, cfg)
+    new = (rows[:, :, None], rows[:, :, None, :0])     # the cache's planes
+    wo = params["wo"].astype(dt)
+
+    if mode == "decode":
+        with jax.named_scope("absorb"):
+            q_lat = jnp.einsum("bshn,rhn->bshr", q[..., :dn],
+                               params["wkv_b"][:, :, :dn].astype(dt))
+            q_lat = jnp.concatenate([q_lat, q_pe], axis=-1)
+        if isinstance(cache, PagedLayerView):
+            cache = paged_gather_layer(cache)
+        kw = {}
+        if defer_write:
+            kw = dict(k_new=new[0], v_new=new[0][..., :r])
+        else:
+            cache = cache_write(cache, *new, step)
+        o = decode_attention(
+            q_lat, LayerCache(k=cache.k, v=cache.k[..., :r], pos=cache.pos),
+            step, window=win, q_per_kv=cfg.n_heads,
+            scale=(dn + cfg.qk_rope_head_dim) ** -0.5, **kw)
+        with jax.named_scope("absorb"):
+            o = jnp.einsum("bshr,rhv->bshv", o,
+                           params["wkv_b"][:, :, dn:].astype(dt))
+        out = jnp.einsum("bshv,hvd->bsd", o, wo)
+        return out, (new if defer_write else cache)
+
+    q = jnp.concatenate([q[..., :dn], q_pe], axis=-1)
+    k, v = _mla_expand(params, rows, cfg)
+    if mode == "suffix":
+        from repro.kernels.ops import suffix_prefill_attention
+        ck, cv = _mla_expand(params, ctx_k[:, :, 0], cfg)
+        o = suffix_prefill_attention(q, k, v, ck, cv, positions, ctx_pos,
+                                     causal=causal, window=win)
+        return jnp.einsum("bshv,hvd->bsd", o, wo), new
+    qb = max(-(-x.shape[1] // 4), 512) if cfg.attn_direct else 512
+    qb = -(-qb // 128) * 128
+    o = chunked_attention(q, k, v, positions, positions, causal=causal,
+                          window=win, q_block=qb, kv_block=qb,
+                          unroll=cfg.attn_direct)
+    out = jnp.einsum("bshv,hvd->bsd", o, wo)
+    if mode == "prefill":
+        W = cache_width or (win if win is not None else x.shape[1])
+        pos2 = (jnp.broadcast_to(positions[None], x.shape[:2])
+                if positions.ndim == 1 else positions)
+        return out, cache_from_prefill(*new, pos2, W)
     return out, None

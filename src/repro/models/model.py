@@ -85,8 +85,12 @@ def init_params(cfg: ModelConfig, key) -> Dict[str, Any]:
         p["layers"] = _stacked(lambda k: init_transformer_layer(k, cfg),
                                ks[1], cfg.n_layers)
     elif t == cb.MOE:
+        nd = cfg.first_dense_layers
         p["layers"] = _stacked(lambda k: init_transformer_layer(k, cfg, moe=True),
-                               ks[1], cfg.n_layers)
+                               ks[1], cfg.n_layers - nd)
+        if nd:
+            p["dense_layers"] = _stacked(
+                lambda k: init_transformer_layer(k, cfg), ks[2], nd)
     elif t == cb.SSM:
         p["layers"] = _stacked(lambda k: init_mamba_layer(k, cfg), ks[1], cfg.n_layers)
     elif t == cb.HYBRID:
@@ -118,19 +122,29 @@ def transformer_layer(lp, x, positions, cfg: ModelConfig, *, mode: str,
                       moe_impl: str = "dense_scan",
                       defer_write: bool = False, ctx_k=None, ctx_v=None,
                       ctx_pos=None):
-    """Pre-norm transformer block.  Returns (x, cache, cross_cache, aux).
+    """Pre-norm transformer block.  Returns (x, cache, cross_cache, aux):
+    aux is the MoE load-balance loss, except in decode, where an MoE block
+    gives each token's number of assignments to its held experts (B,).
 
     In decode mode with ``defer_write``, the second return is the (k, v) pair
     of the new token instead of an updated cache (one post-scan scatter).
     In suffix mode the second return is the (k, v) pair of the chunk tokens
     (same deferred-write contract), attending over ``ctx_k``/``ctx_v``/
-    ``ctx_pos`` — the already-cached prompt context."""
-    use_rope = not cfg.age_encoding
-    a, new_cache = attn_lib.attention(
-        lp["attn"], apply_norm(lp["attn_norm"], x, cfg), positions, cfg,
-        mode=mode, cache=cache, step=step, causal=causal,
-        use_rope=use_rope, cache_width=cache_width, defer_write=defer_write,
-        ctx_k=ctx_k, ctx_v=ctx_v, ctx_pos=ctx_pos)
+    ``ctx_pos`` — the already-cached prompt context.  Latent attention
+    (``cfg.is_mla``) caches one row per token in the k plane."""
+    h = apply_norm(lp["attn_norm"], x, cfg)
+    if cfg.is_mla:
+        a, new_cache = attn_lib.mla_attention(
+            lp["attn"], h, positions, cfg, mode=mode, cache=cache,
+            step=step, causal=causal, cache_width=cache_width,
+            defer_write=defer_write, ctx_k=ctx_k, ctx_pos=ctx_pos)
+    else:
+        a, new_cache = attn_lib.attention(
+            lp["attn"], h, positions, cfg,
+            mode=mode, cache=cache, step=step, causal=causal,
+            use_rope=not cfg.age_encoding, cache_width=cache_width,
+            defer_write=defer_write, ctx_k=ctx_k, ctx_v=ctx_v,
+            ctx_pos=ctx_pos)
     x = x + a
     new_cross = cross_cache
     if "cross_attn" in lp:
@@ -145,7 +159,11 @@ def transformer_layer(lp, x, positions, cfg: ModelConfig, *, mode: str,
         x = x + c
     h = apply_norm(lp["mlp_norm"], x, cfg)
     aux = jnp.zeros((), jnp.float32)
-    if "moe" in lp:
+    if "moe" in lp and mode == "decode":
+        y, _, held = moe_lib.apply_moe(lp["moe"], h, cfg, impl=moe_impl,
+                                       count=True)
+        aux = held[:, 0]
+    elif "moe" in lp:
         y, aux = moe_lib.apply_moe(lp["moe"], h, cfg, impl=moe_impl)
     else:
         y = apply_mlp(lp["mlp"], h, cfg)
@@ -197,26 +215,37 @@ def _stack_trees(trees):
     return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *trees)
 
 
+def _cat_layers(a, b):
+    """Two layer-stacked trees (caches, K/V rows) as one, ``a``'s first;
+    None stays None (train mode has no caches)."""
+    if a is None:
+        return b
+    return jax.tree_util.tree_map(lambda x, y: jnp.concatenate([x, y]), a, b)
+
+
 def _transformer_stack_unrolled(layers, x, positions, cfg, *, mode,
                                 memory=None, causal=True, caches=None,
                                 cross_caches=None, step=None, cache_width=None,
-                                moe_impl="dense_scan", has_cross=False):
+                                moe_impl="dense_scan", has_cross=False,
+                                head=None):
     """Python-loop twin of _transformer_stack (cfg.unroll_layers cost mode)."""
     if isinstance(caches, attn_lib.PagedCache):
         raise ValueError("paged KV cache requires the scanned stack "
                          "(cfg.unroll_layers is a cost-accounting mode)")
-    L = jax.tree_util.tree_leaves(layers)[0].shape[0]
+    stacks = [layers] if head is None else [head, layers]
+    lps = [_slice_layer(t, i) for t in stacks
+           for i in range(jax.tree_util.tree_leaves(t)[0].shape[0])]
     aux = jnp.zeros((), jnp.float32)
     out_caches, out_cross, kvs = [], [], []
-    for i in range(L):
-        lp = _slice_layer(layers, i)
+    for i, lp in enumerate(lps):
         if mode == "decode":
-            x, kv, _, _ = transformer_layer(
+            x, kv, _, a = transformer_layer(
                 lp, x, positions, cfg, mode="decode",
                 cache=_slice_layer(caches, i),
                 cross_cache=(_slice_layer(cross_caches, i) if has_cross
                              else None),
                 step=step, moe_impl=moe_impl, defer_write=True)
+            aux = aux + a
             kvs.append(kv)
         else:
             def call(lp_, h_):
@@ -241,15 +270,30 @@ def _transformer_stack_unrolled(layers, x, positions, cfg, *, mode,
 
 def _transformer_stack(layers, x, positions, cfg, *, mode, memory=None,
                        causal=True, caches=None, cross_caches=None, step=None,
-                       cache_width=None, moe_impl="dense_scan", has_cross=False):
-    """Scan a stacked transformer.  In decode mode caches are scan xs; in
-    prefill they are scan ys; in train they don't exist."""
+                       cache_width=None, moe_impl="dense_scan", has_cross=False,
+                       head=None):
+    """Scan a stacked transformer.  In decode mode caches are scan xs (read
+    by layer index where a ``head`` shares them); in prefill they are scan
+    ys; in train they don't exist.
+
+    ``head``: leading layers of another kind (an MoE stack's dense layers),
+    run first; their caches come first on the cache's layer axis.  The
+    fourth return is the summed ``aux`` of :func:`transformer_layer`: the
+    load-balance loss, or in decode each token's assignments to held
+    experts (B,)."""
     if cfg.unroll_layers:
         return _transformer_stack_unrolled(
             layers, x, positions, cfg, mode=mode, memory=memory,
             causal=causal, caches=caches, cross_caches=cross_caches,
             step=step, cache_width=cache_width, moe_impl=moe_impl,
-            has_cross=has_cross)
+            has_cross=has_cross, head=head)
+    if head is not None and mode != "decode":
+        x, hc, _, aux = _transformer_stack(head, x, positions, cfg, mode=mode,
+                                           cache_width=cache_width)
+        x, c, _, aux_l = _transformer_stack(layers, x, positions, cfg,
+                                            mode=mode, cache_width=cache_width,
+                                            moe_impl=moe_impl)
+        return x, _cat_layers(hc, c), None, aux + aux_l
     if mode == "train":
         def body(h, lp):
             h, _, _, aux = transformer_layer(
@@ -275,48 +319,48 @@ def _transformer_stack(layers, x, positions, cfg, *, mode, memory=None,
     # collected and written with ONE stacked scatter afterwards (avoids
     # round-tripping the full cache through scan temporaries)
     if isinstance(caches, attn_lib.PagedCache):
-        # paged decode: the scan carries each layer's pool planes; the
-        # block ids and ring positions have no layer axis, so they are
-        # built once here and closed over.  decode_attention dispatches on
-        # the PagedLayerView.
+        # the scan carries each layer's pool planes; the block ids and ring
+        # positions have no layer axis, so they are built once here and
+        # closed over.  decode_attention dispatches on the PagedLayerView.
         if has_cross:
             raise ValueError("paged KV cache does not support cross-"
                              "attention stacks")
-        pc = caches
-        blocks, ring_pos = attn_lib.paged_ring_index(pc.pos, pc.table)
+        blocks, ring_pos = attn_lib.paged_ring_index(caches.pos, caches.table)
+        planes = (caches.k, caches.v)
 
-        def body(h, xs):
-            lp, kl, vl = xs
-            view = attn_lib.PagedLayerView(kl, vl, blocks, ring_pos)
-            h, kv, _, _ = transformer_layer(
-                lp, h, positions, cfg, mode="decode", cache=view, step=step,
-                moe_impl=moe_impl, defer_write=True)
-            return h, kv
-        x, (k_news, v_news) = jax.lax.scan(body, x, (layers, pc.k, pc.v))
-        caches = attn_lib.cache_write_stacked(pc, k_news, v_news, step)
-        return x, caches, None, jnp.zeros((), jnp.float32)
+        def view(kv):
+            return attn_lib.PagedLayerView(kv[0], kv[1], blocks, ring_pos)
+    else:
+        planes = caches
 
-    if has_cross:
+        def view(c):
+            return c
+
+    def scan(x, stack, per_layer, read, xcs):
         def body(h, xs):
             lp, c, xc = xs
-            h, kv, _, _ = transformer_layer(
-                lp, h, positions, cfg, mode="decode", cache=c, cross_cache=xc,
-                step=step, moe_impl=moe_impl, defer_write=True)
-            return h, kv
-        x, (k_news, v_news) = jax.lax.scan(
-            body, x, (layers, caches, cross_caches))
-        caches = attn_lib.cache_write_stacked(caches, k_news, v_news, step)
-        return x, caches, cross_caches, jnp.zeros((), jnp.float32)
+            h, kv, _, aux = transformer_layer(
+                lp, h, positions, cfg, mode="decode", cache=read(c),
+                cross_cache=xc, step=step, moe_impl=moe_impl,
+                defer_write=True)
+            return h, (kv, aux)
+        x, (kv, aux) = jax.lax.scan(body, x, (stack, per_layer, xcs))
+        return x, kv, jnp.sum(aux, axis=0)
 
-    def body(h, xs):
-        lp, c = xs
-        h, kv, _, _ = transformer_layer(
-            lp, h, positions, cfg, mode="decode", cache=c, step=step,
-            moe_impl=moe_impl, defer_write=True)
-        return h, kv
-    x, (k_news, v_news) = jax.lax.scan(body, x, (layers, caches))
-    caches = attn_lib.cache_write_stacked(caches, k_news, v_news, step)
-    return x, caches, None, jnp.zeros((), jnp.float32)
+    if head is None:
+        x, kv, aux = scan(x, layers, planes, view, cross_caches)
+    else:
+        # the cache's layer axis spans both stacks: each layer reads its
+        # planes by index (slicing the pool per stack would copy it)
+        def at(i):
+            return view(jax.tree_util.tree_map(lambda a: a[i], planes))
+        nd = jax.tree_util.tree_leaves(head)[0].shape[0]
+        n = jax.tree_util.tree_leaves(layers)[0].shape[0]
+        x, kv_head, _ = scan(x, head, jnp.arange(nd), at, None)
+        x, kv, aux = scan(x, layers, jnp.arange(nd, nd + n), at, None)
+        kv = _cat_layers(kv_head, kv)
+    caches = attn_lib.cache_write_stacked(caches, kv[0], kv[1], step)
+    return x, caches, cross_caches, aux
 
 
 def _suffix_stack(layers, x, positions, cfg, *, ctx_k, ctx_v, ctx_pos,
@@ -366,9 +410,18 @@ def forward_suffix(params, cfg: ModelConfig, batch: Dict[str, Any], ctx,
     if cfg.age_encoding:
         x = x + age_encoding(batch["ages"], cfg.d_model).astype(x.dtype)
     positions = batch["positions"]
+    ck, cv = ctx["k"], ctx["v"]
+    head = params.get("dense_layers")
+    if head is not None:
+        nd = cfg.first_dense_layers
+        x, hk, hv = _suffix_stack(head, x, positions, cfg, ctx_k=ck[:nd],
+                                  ctx_v=cv[:nd], ctx_pos=ctx["pos"])
+        ck, cv = ck[nd:], cv[nd:]
     x, k_news, v_news = _suffix_stack(
-        params["layers"], x, positions, cfg, ctx_k=ctx["k"], ctx_v=ctx["v"],
+        params["layers"], x, positions, cfg, ctx_k=ck, ctx_v=cv,
         ctx_pos=ctx["pos"], moe_impl=moe_impl)
+    if head is not None:
+        k_news, v_news = _cat_layers((hk, hv), (k_news, v_news))
     idx = jnp.asarray(last_index, jnp.int32)
     x = jnp.take_along_axis(x, idx[:, None, None], axis=1)
     x = apply_norm(params["final_norm"], x, cfg)
@@ -533,7 +586,8 @@ def forward(params, cfg: ModelConfig, batch: Dict[str, Any], *,
     if t in (cb.DENSE, cb.VLM, cb.MOE):
         x, caches, _, aux = _transformer_stack(
             params["layers"], x, pos, cfg, mode=mode,
-            cache_width=cache_width, moe_impl=moe_impl)
+            cache_width=cache_width, moe_impl=moe_impl,
+            head=params.get("dense_layers"))
         if mode == "prefill":
             out["cache"] = {"self": caches}
     elif t == cb.SSM:
@@ -583,7 +637,9 @@ def decode_step(params, cfg: ModelConfig, cache, batch: Dict[str, Any], step,
     """One-token decode.  batch["tokens"]: (B, 1); step: scalar int32 absolute
     position of the new token, or (B,) per-example positions — the serving
     engine advances its continuous-batching slots, each at a different depth,
-    in one batched call.  Returns {"logits": (B, 1, V), "cache": ...}."""
+    in one batched call.  Returns {"logits": (B, 1, V), "cache": ...}, and
+    for MoE stacks "expert_tokens": (B,) int32, each token's assignments to
+    held experts summed over layers."""
     t = cfg.arch_type
     tokens = batch["tokens"]
     x = embed_tokens(params["embed"], tokens, cfg)
@@ -592,11 +648,15 @@ def decode_step(params, cfg: ModelConfig, cache, batch: Dict[str, Any], step,
     step = jnp.asarray(step, jnp.int32)
     pos = step if step.ndim == 1 else jnp.reshape(step, (1,))
 
+    out: Dict[str, Any] = {}
     if t in (cb.DENSE, cb.VLM, cb.MOE):
-        x, caches, _, _ = _transformer_stack(
+        x, caches, _, held = _transformer_stack(
             params["layers"], x, pos, cfg, mode="decode",
-            caches=cache["self"], step=step, moe_impl=moe_impl)
+            caches=cache["self"], step=step, moe_impl=moe_impl,
+            head=params.get("dense_layers"))
         new_cache = {"self": caches}
+        if t == cb.MOE:
+            out["expert_tokens"] = held.astype(jnp.int32)
     elif t == cb.SSM:
         x, caches = _ssm_stack(params["layers"], x, cfg, mode="decode",
                                caches=cache["ssm"])
@@ -615,7 +675,8 @@ def decode_step(params, cfg: ModelConfig, cache, batch: Dict[str, Any], step,
         raise ValueError(t)
 
     x = apply_norm(params["final_norm"], x, cfg)
-    return {"logits": logits_head(params["embed"], x, cfg), "cache": new_cache}
+    return {"logits": logits_head(params["embed"], x, cfg), "cache": new_cache,
+            **out}
 
 
 def mask_padded_positions(cache, last_idx):

@@ -234,13 +234,26 @@ def _pack(adv):
                       adv["finished"].astype(jnp.float32)])
 
 
+#: MoE dispatch (``repro.models.moe``) per program: grouped matmuls for the
+#: prefills, whose thousands of tokens spread over every expert, and for the
+#: decode tick the scan over held experts, which the chip ran faster at 32
+#: slots (106.0 against 109.4 ms a Moonlight tick on a TPU v5e; PERF.md)
+_MOE_PREFILL = "ragged"
+_MOE_DECODE = "dense_scan"
+#: ring rows (batch bucket x max_context) one admission prefill may return:
+#: a group that would pass it splits, which bounds the prefill's cache rows
+#: and its activations
+PREFILL_ROWS = 16384
+
+
 def _tick_core(params, cache, state, u, cfg: ModelConfig, kn: _Knobs):
     """Decode every slot one event, sample, advance: -> (cache, state,
     packed host columns, the (slots, V) float32 logits sampled from)."""
     batch = {"tokens": state["last"][:, None]}
     if cfg.age_encoding:
         batch["ages"] = state["age"][:, None]
-    d = decode_step(params, cfg, cache, batch, state["step"])
+    d = decode_step(params, cfg, cache, batch, state["step"],
+                    moe_impl=_MOE_DECODE)
     lg = d["logits"][:, 0].astype(jnp.float32)
     next_step = jnp.where(state["active"], state["step"] + 1, state["step"])
     adv = _advance(lg, u, state["age"], state["n_emitted"], state["max_new"],
@@ -253,7 +266,13 @@ def _tick_core(params, cache, state, u, cfg: ModelConfig, kn: _Knobs):
         "max_new": state["max_new"],
         "active": state["active"] & ~adv["finished"],
     }
-    return d["cache"], new_state, _pack(adv), lg
+    packed = _pack(adv)
+    if cfg.arch_type == cb.MOE:
+        # a fifth row, in the same fetch: each active slot's assignments
+        # to the held experts, summed over MoE layers
+        held = jnp.where(state["active"], d["expert_tokens"], 0)
+        packed = jnp.concatenate([packed, held.astype(jnp.float32)[None]])
+    return d["cache"], new_state, packed, lg
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "kn"),
@@ -277,7 +296,8 @@ def _prefill_core(params, tokens, ages, last_idx, age0, lengths, max_new, u,
     if cfg.age_encoding:
         batch["ages"] = ages
     out = forward(params, cfg, batch, mode="prefill",
-                  cache_width=kn.max_context, last_index=last_idx)
+                  cache_width=kn.max_context, last_index=last_idx,
+                  moe_impl=_MOE_PREFILL)
     cache_rows = mask_padded_positions(out["cache"], last_idx)
     lg = out["logits"][:, 0].astype(jnp.float32)
     nb = tokens.shape[0]
@@ -544,7 +564,8 @@ def _suffix_chunk_jit(params, cache, tokens, ages, positions, ctx_ids, dst,
         batch["ages"] = ages
     out = forward_suffix(params, cfg, batch,
                          {"k": gather(pc.k), "v": gather(pc.v),
-                          "pos": ctx_pos}, last_index=last_idx)
+                          "pos": ctx_pos}, last_index=last_idx,
+                         moe_impl=_MOE_PREFILL)
     Sc = tokens.shape[1]
     nblk = Sc // bs
     d = dst.reshape(-1)
@@ -748,6 +769,9 @@ class BatchedEngine:
         self.chunked_prefills = 0                          # guarded-by: _lock
         self.prefill_chunks = 0                            # guarded-by: _lock
         self.suffix_tokens_saved = 0                       # guarded-by: _lock
+        # MoE stacks: token->expert assignments that landed on the held
+        # experts, summed over MoE layers and ticks (the tick's fifth row)
+        self.expert_tokens = 0                             # guarded-by: _lock
         self.prefill_shapes: set = set()                   # guarded-by: engine-thread
         # phase spans of the engine's steps and its callers' requests
         self.spans = Spans()
@@ -1051,7 +1075,8 @@ class BatchedEngine:
                 "events_tick": self.events_tick,
                 "events_admit": self.events_admit,
                 "admitted": self.admitted,
-                "queue_wait_s": self.queue_wait_s}
+                "queue_wait_s": self.queue_wait_s,
+                "expert_tokens": self.expert_tokens}
         # repro-lint: disable=RL001 GIL-atomic scan of the slot list for a
         # monitoring count; entries may be mid-swap but never torn
         active = sum(r is not None for r in self.slot_req)
@@ -1551,6 +1576,9 @@ class BatchedEngine:
             if group and (self._eff_len(cand) > self.max_context
                           or cand.hold):
                 break
+            if group and min(_next_pow2(len(group) + 1), self.slots) \
+                    * self.max_context > PREFILL_ROWS:
+                break                      # the rest prefill as a next group
             plan = admissible(cand)
             if plan is None or (group and plan[0] == "ref"):
                 break                      # complete hits admit solo next
@@ -2124,8 +2152,10 @@ class BatchedEngine:
             emitted = 0
             for slot in active:
                 emitted += self._apply_host(self.slot_req[slot], slot,
-                                            arr[:, slot])
+                                            arr[:4, slot])
             self.events_tick += emitted
+            if len(arr) > 4:
+                self.expert_tokens += int(arr[4, active].sum())
         return True
 
     def run(self, max_ticks: int = 10_000) -> List[Request]:  # repro-lint: engine-thread-only

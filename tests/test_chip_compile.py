@@ -151,3 +151,32 @@ def test_flash_attention_compiles(one_chip, delphi, seq):
                              jnp.bfloat16, sharding=one_chip)
     c = _compile(ops.flash_attention, x, x, x, interpret=False)
     assert _kernels(c) == 1
+
+
+def test_moonlight_tick_compiles_and_fits_one_chip(one_chip):
+    """Moonlight-16B-A3B's decode tick serving long documents (32 slots x
+    4,096, a latent pool of 6,145 blocks, bfloat16 weights with 8 of 64
+    experts held): the compiler takes it, and weights, pool and the
+    tick's temporaries (among them the scatter's copy of the whole pool)
+    fit in one v5e's 16 GiB with room to spare."""
+    cfg = get_config("moonlight-16b-a3b").replace(
+        n_experts=8, n_router_experts=64, param_dtype="bfloat16")
+    slots, ctx, blocks = 32, 4096, 6145
+    params = jax.eval_shape(lambda k: init_params(cfg, k),
+                            jax.random.PRNGKey(0))
+    cache = jax.eval_shape(lambda: make_paged_decode_cache(
+        cfg, slots, ctx, num_blocks=blocks, block_size=BLOCK))
+    assert cache["self"].k.shape == (27, blocks, 1, BLOCK, 576)
+    i32, f32 = jnp.int32, jnp.float32
+    dtypes = {"last": i32, "age": f32, "step": i32, "n_emitted": i32,
+              "max_new": i32, "active": jnp.bool_}
+    state = {k: jax.ShapeDtypeStruct((slots,), d) for k, d in dtypes.items()}
+    u = jax.ShapeDtypeStruct((slots, cfg.vocab_size), f32)
+    kn = _Knobs(slots=slots, max_context=ctx, is_delphi=False,
+                use_pallas=False, inv_temp=1e6, max_age=cfg.max_age,
+                death_token=cfg.death_token, vocab=cfg.vocab_size)
+    c = _tick_u_jit.lower(*_on((params, cache, state, u), one_chip),
+                          cfg=cfg, kn=kn).compile()
+    mem = c.memory_analysis()
+    used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert used < 15 * 2**30, used

@@ -5,6 +5,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config
+from repro.models.layers import apply_mlp
 from repro.models.moe import apply_moe, init_moe, route
 
 
@@ -71,3 +72,55 @@ def test_expert_gradients_flow(moe_setup):
     assert np.isfinite(gnorm) and gnorm > 0
     # router must receive gradient (load-balance + combine weights)
     assert float(jnp.sum(jnp.abs(g["router"]))) > 0
+
+
+@pytest.fixture(scope="module")
+def sigmoid_setup():
+    """Moonlight-style routing at a small size: 8 routed experts, top-3 by
+    sigmoid score plus a correction bias, 1 shared expert."""
+    cfg = get_config("moonlight-16b-a3b", reduced=True).replace(
+        dtype="float32", n_experts=8, top_k=3)
+    params = init_moe(jax.random.PRNGKey(0), cfg)
+    params["router_bias"] = 0.3 * jax.random.normal(
+        jax.random.PRNGKey(2), params["router_bias"].shape)
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 24, cfg.d_model))
+    return cfg, params, x
+
+
+def test_sigmoid_routing_picks_by_biased_score_weighs_by_score(sigmoid_setup):
+    cfg, params, x = sigmoid_setup
+    flat = np.asarray(x.reshape(-1, cfg.d_model), np.float64)
+    w, e, _ = route(params, jnp.asarray(flat, jnp.float32), cfg)
+    scores = 1 / (1 + np.exp(-flat @ np.asarray(params["router"], np.float64)))
+    biased = scores + np.asarray(params["router_bias"], np.float64)
+    want = np.argsort(-biased, axis=-1)[:, :cfg.top_k]
+    np.testing.assert_array_equal(np.sort(np.asarray(e), -1),
+                                  np.sort(want, -1))
+    # the bias changes the choice somewhere, so selection did not ignore it
+    assert (np.sort(np.argsort(-scores, -1)[:, :cfg.top_k], -1)
+            != np.sort(want, -1)).any()
+    chosen = np.take_along_axis(scores, np.asarray(e), -1)
+    np.testing.assert_allclose(
+        w, chosen / chosen.sum(-1, keepdims=True) * cfg.routed_scaling,
+        rtol=1e-5)
+
+
+@pytest.mark.parametrize("impl", ["dense_scan", "ragged", "dense_einsum"])
+def test_expert_shares_sum_to_the_uncut_layer(sigmoid_setup, impl):
+    """Two chips' shares (experts 0-3 and 4-7 of 8), with the shared
+    expert every chip computes counted once, add up to the uncut layer."""
+    cfg, params, x = sigmoid_setup
+    whole, _ = apply_moe(params, x, cfg, impl=impl)
+    shares, held = [], 0
+    for e0 in (0, 4):
+        c = cfg.replace(n_experts=4, n_router_experts=8, expert_offset=e0)
+        p = dict(params, **{k: params[k][e0:e0 + 4]
+                            for k in ("w_gate", "w_up", "w_down")})
+        y, _, n = apply_moe(p, x, c, impl=impl, count=True)
+        shares.append(y)
+        held = held + n
+    shared = apply_mlp(params["shared"], x, cfg)
+    np.testing.assert_allclose(shares[0] + shares[1] - shared, whole,
+                               atol=1e-5)
+    # every assignment landed on exactly one share
+    np.testing.assert_array_equal(held, cfg.top_k)

@@ -52,6 +52,31 @@ def test_allocator_free_list():
         a.release(ids + [1, 2])         # over-free detected
 
 
+def test_admission_prefill_splits_at_its_row_budget(setup, monkeypatch):
+    """An admission group whose prefill would return more ring rows
+    (batch bucket x max_context) than ``PREFILL_ROWS`` splits: the rest
+    prefill as further groups of the same step, and all are served."""
+    from repro.serve import engine as engine_mod
+    params, cfg = setup
+    for rows, groups in ((None, 1), (2 * 32, 4)):
+        if rows is not None:
+            monkeypatch.setattr(engine_mod, "PREFILL_ROWS", rows)
+        eng = BatchedEngine(params, cfg, slots=8, max_context=32,
+                            cache="paged")
+        reqs = [_req(s, uniforms=_uniforms(8, cfg.vocab_size, seed=s))
+                for s in range(8)]
+        for r in reqs:
+            eng.submit(r)
+        eng.step()                  # one step admits all eight
+        assert all(r is not None for r in eng.slot_req)
+        assert eng.admit_batches == groups
+        assert eng.prefill_shapes == {(8 // groups, 8)}
+        eng.run()
+        assert all(r.done and r.error is None and r.out_tokens
+                   for r in reqs)
+        assert eng.allocator.used == 0
+
+
 def test_engine_rejects_bad_paged_config(setup):
     params, cfg = setup
     with pytest.raises(ValueError, match="multiple"):
