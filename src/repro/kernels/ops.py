@@ -79,13 +79,14 @@ def paged_decode_attention(q, k_pool, v_pool, table, pos, step, *,
     """Fused paged decode: block-table gather + online softmax in one pass.
 
     q: (B, Hq, hd) one roped query token per slot; k/v_pool:
-    (NB, Hkv, bs, hd) shared block pool; table: (B, nbs) pool ids (-1 =
-    unallocated); pos: (NB, bs) absolute positions (-1 = empty); step: (B,)
-    per-slot query positions.  GQA by the same (Hkv, G) grouping as
-    ``decode_attention``.  Returns (B, Hq, hd).
+    (NB, bs * Hkv * hd) one layer of the held pool (token-major rows,
+    ``PagedCache``); table: (B, nbs) pool ids (-1 = unallocated); pos:
+    (NB, bs) absolute positions (-1 = empty); step: (B,) per-slot query
+    positions.  GQA by the same (Hkv, G) grouping as ``decode_attention``.
+    Returns (B, Hq, hd).
     """
     B, Hq, hd = q.shape
-    Hkv = k_pool.shape[1]
+    Hkv = k_pool.shape[1] // (pos.shape[1] * hd)
     q4 = q.reshape(B, Hkv, Hq // Hkv, hd)
     out = _call(_paged, q4, k_pool, v_pool, table, pos, step, window=window,
                 interpret=interpret)

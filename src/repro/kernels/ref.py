@@ -79,22 +79,23 @@ def paged_decode_attention_ref(q, k_pool, v_pool, table, pos, step,
                                window: Optional[int] = None) -> jax.Array:
     """Paged single-token decode oracle: dense gather + masked softmax.
 
-    q: (B, Hkv, G, hd); k/v_pool: (NB, Hkv, bs, hd); table: (B, nbs) int32
-    pool ids (-1 = unallocated); pos: (NB, bs) int32 absolute positions
-    (-1 = empty); step: (B,) query positions.  Each slot attends its valid
-    ring window ``(step - W, step]`` where ``W = nbs * bs``; requires at
-    least one valid position per slot.  Returns (B, Hkv, G, hd) fp32.
+    q: (B, Hkv, G, hd); k/v_pool: (NB, bs * Hkv * hd) token-major block
+    rows; table: (B, nbs) int32 pool ids (-1 = unallocated); pos: (NB, bs)
+    int32 absolute positions (-1 = empty); step: (B,) query positions.
+    Each slot attends its valid ring window ``(step - W, step]`` where
+    ``W = nbs * bs``; requires at least one valid position per slot.
+    Returns (B, Hkv, G, hd) fp32.
     """
     B, Hkv, G, hd = q.shape
-    bs = k_pool.shape[2]
+    NB, bs = pos.shape
     nbs = table.shape[1]
     W = nbs * bs
     j = jnp.arange(W)
     blk = table[:, j // bs]                            # (B, W)
     off = jnp.broadcast_to(j % bs, (B, W))
     safe = jnp.maximum(blk, 0)
-    k = k_pool[safe, :, off, :].astype(jnp.float32)    # (B, W, Hkv, hd)
-    v = v_pool[safe, :, off, :].astype(jnp.float32)
+    k = k_pool.reshape(NB, bs, Hkv, hd)[safe, off].astype(jnp.float32)
+    v = v_pool.reshape(NB, bs, Hkv, hd)[safe, off].astype(jnp.float32)
     p = jnp.where(blk >= 0, pos[safe, off], -1)
     s = jnp.einsum("bhgd,bwhd->bhgw", q.astype(jnp.float32), k) * hd ** -0.5
     stp = step.reshape(B, 1, 1, 1)

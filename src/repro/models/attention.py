@@ -47,26 +47,39 @@ class PagedCache(NamedTuple):
     the serving lever for Delphi's short-median/long-tail trajectories.
 
     Leaves:
-      k, v  : (L, num_blocks, Hkv, block_size, hd) — the shared pool.
+      k, v  : (L, num_blocks, block_size * Hkv * hd) — the shared pool, one
+              row per (layer, block) holding the block's tokens in order,
+              each token's ``Hkv * hd`` values together (token-major).
               Block 0 is the **trash block**: writes of slots with no
               allocated destination land there and are never read back.
               Latent attention keeps one ``[c | k_pe]`` row a token in the
               k plane (Hkv 1) and an empty v plane (width 0): the same
-              blocks, gather and scatter (:func:`cache_rows`).
+              blocks, gather and writes (:func:`cache_rows`).
       pos   : (num_blocks, block_size) int32 absolute positions, -1 = empty.
               Layer-independent (every layer writes the same positions).
       table : (B, blocks_per_slot) int32 pool block ids, -1 = unallocated.
 
+    Why rows: the TPU tiles an array's two minor axes, and a
+    ``(block_size, hd)`` minor pair such as ``(16, 10)`` would pad, so it
+    holds a ``(L, NB, Hkv, bs, hd)`` pool with the block axis minor and
+    every scatter into it lays the whole pool out afresh.  Rows of
+    ``bs * Hkv * hd`` (1,920 for Delphi-2M, 10,240 for Danube, 9,216 for
+    Moonlight's latent rows) are whole lane tiles, so the pool is held as
+    written: the tick's write (:func:`paged_write_stacked`), the per-layer
+    gather (:func:`paged_gather_rows`) and the engine's block copies
+    (:func:`paged_put_rows`) all address it in place.
+
     The logical layout is *exactly* the ring cache factored through one
     indirection: with ``W = blocks_per_slot * block_size``, the token at
-    absolute position ``p`` of slot ``b`` lives at
-    ``pool[table[b, (p % W) // block_size], p % block_size]`` — the same
-    ``p % W`` ring slot the dense cache uses: ring positions
+    absolute position ``p`` of slot ``b`` lives in row
+    ``table[b, (p % W) // block_size]`` at token ``p % block_size`` — the
+    same ``p % W`` ring slot the dense cache uses: ring positions
     ``[jb * block_size, (jb + 1) * block_size)`` of slot ``b`` are exactly
-    pool block ``table[b, jb]``.  ``paged_gather_layer`` therefore
-    reconstructs a bit-identical ``LayerCache`` view, which is
-    what makes the paged engine's trajectories bit-equal to the ring
-    engine's under injected uniforms.
+    pool block ``table[b, jb]``.  The gather therefore reads exactly the
+    ring's values (``paged_gather_layer`` reconstructs the bit-identical
+    ``LayerCache`` view), and :func:`decode_attention` reads both in one
+    formulation, which is what makes the paged engine's trajectories
+    bit-equal to the ring engine's under injected uniforms.
     """
     k: jax.Array
     v: jax.Array
@@ -75,12 +88,25 @@ class PagedCache(NamedTuple):
 
 
 class PagedLayerView(NamedTuple):
-    """One layer's pool planes plus the tick's layer-independent ring index
+    """One layer of the pool plus the tick's layer-independent ring index
     (:func:`paged_ring_index`, built once outside the layer scan) — what the
-    decode layer scan hands to :func:`decode_attention`."""
-    k: jax.Array          # (num_blocks, Hkv, block_size, hd)
+    decode layer scan hands to :func:`decode_attention`.  The planes are
+    the whole stacked pool, read at ``layer`` by the gather itself: a
+    per-layer slice of the pool would be a copy of it."""
+    k: jax.Array          # (L, num_blocks, block_size * heads * hd)
     v: jax.Array
+    layer: jax.Array      # () int32 layer index into the planes
     blocks: jax.Array     # (B, blocks_per_slot) pool ids, -1 -> trash block 0
+    pos: jax.Array        # (B, W) int32 absolute position per ring slot
+    heads: int            # Hkv: how a token's values split into heads
+
+
+class KVRows(NamedTuple):
+    """One layer's keys and values with each token's heads side by side:
+    the paged pool's own token-major order (:func:`paged_gather_rows`), and
+    the form :func:`decode_attention` reads every cache in."""
+    k: jax.Array          # (B, W, heads * hd)
+    v: jax.Array          # (B, W, heads * dv)
     pos: jax.Array        # (B, W) int32 absolute position per ring slot
 
 
@@ -267,42 +293,108 @@ def paged_ring_index(pos, table):
     return blocks, ring_pos.reshape(B, -1).astype(jnp.int32)
 
 
+def paged_rows(a, bs: int):
+    """Token-major ``(..., n * bs, Hkv, hd)`` -> the pool's rows ``(..., n,
+    bs * Hkv * hd)``: block ``i`` holds tokens ``[i * bs, (i + 1) * bs)``,
+    each token's heads side by side.  Inverse of :func:`paged_tokens`."""
+    *lead, T, H, hd = a.shape
+    # explicit widths: a latent value plane is zero-wide, and a reshape
+    # cannot infer a -1 axis of a zero-size array
+    return a.reshape(*lead, T // bs, bs * H * hd)
+
+
+def paged_tokens(rows, bs: int, heads: int):
+    """The pool's rows ``(..., n, bs * heads * hd)`` -> token-major ``(...,
+    n * bs, heads, hd)``.  Inverse of :func:`paged_rows`."""
+    *lead, n, R = rows.shape
+    return rows.reshape(*lead, n * bs, heads, R // (bs * heads))
+
+
 @jax.named_scope("paged_gather")
-def paged_gather_layer(view: PagedLayerView) -> LayerCache:
-    """Reconstruct the dense ring view of one layer's paged cache.
+def paged_gather_rows(view: PagedLayerView) -> KVRows:
+    """Gather one layer's paged context in the form decode reads it.
 
     Ring slots ``[jb * bs, (jb + 1) * bs)`` of slot ``b`` are the whole pool
-    block ``blocks[b, jb]``, so K and V are gathered one block per table
-    entry and laid out ``(B, Hkv, W, hd)``; unallocated entries read
-    (masked) garbage from the trash block and carry ``pos = -1`` — so the
-    result feeds the unchanged :func:`decode_attention` math and the paged
-    decode is bit-identical to the ring decode.  Each block is read as
-    ``Hkv`` rows of ``bs * hd``: on the TPU that view gathers and lays out
-    cheaper than ``(Hkv, bs, hd)``, whose narrow minor axes XLA pads.  (The
-    dense gather is a transient; the fused no-materialization read lives in
-    ``repro.kernels.paged_decode_attention``.)
+    block ``blocks[b, jb]``, so each plane is gathered one row per table
+    entry straight from the held ``(L, NB, bs * Hkv * hd)`` pool (layer and
+    block in one index, no per-layer slice) and read on as ``(B, W, Hkv *
+    hd)``, the rows' own order: only the gathered transient is laid out,
+    never the pool.  Unallocated entries read (masked) garbage from the
+    trash block and carry ``pos = -1``.  (The fused no-materialization read
+    lives in ``repro.kernels.paged_decode_attention``.)
     """
     B, nbs = view.blocks.shape
+    W = view.pos.shape[1]
 
-    def ring(pool):          # (B, nbs, Hkv, bs * hd) -> (B, Hkv, W, hd)
-        NB, Hkv, bs, hd = pool.shape
-        rows = pool.reshape(NB, Hkv, bs * hd)[view.blocks]
-        return rows.transpose(0, 2, 1, 3).reshape(B, Hkv, nbs * bs, hd)
+    def rows(pool):          # (L, NB, bs * H * hd) -> (B, W, H * hd)
+        return pool[view.layer, view.blocks].reshape(
+            B, W, pool.shape[-1] * nbs // W)
 
-    return LayerCache(k=ring(view.k), v=ring(view.v), pos=view.pos)
+    return KVRows(k=rows(view.k), v=rows(view.v), pos=view.pos)
+
+
+def paged_gather_layer(view: PagedLayerView) -> LayerCache:
+    """The dense ring view ``(B, Hkv, W, hd)`` of one layer's paged cache:
+    the ring cache's slots, bit for bit, wherever the table is allocated.
+    The tests and ``scripts/pool_layout_check.py`` hold the pool to the ring
+    it factors through it; decode reads :func:`paged_gather_rows`."""
+    r = paged_gather_rows(view)
+    B, nbs = view.blocks.shape
+    bs = view.pos.shape[1] // nbs
+
+    def ring(a):             # (B, W, H * hd) -> (B, H, W, hd)
+        return paged_tokens(a.reshape(B, nbs, bs * a.shape[-1]), bs,
+                            view.heads).transpose(0, 2, 1, 3)
+
+    return LayerCache(k=ring(r.k), v=ring(r.v), pos=r.pos)
+
+
+def paged_put_rows(caches: PagedCache, dst, k_rows, v_rows, pos_rows,
+                   cols=None) -> PagedCache:
+    """Write into pool blocks ``dst`` (n,), in order, in place.
+
+    ``k_rows``/``v_rows`` (L, n, w) and ``pos_rows`` (n, p): entry ``i``
+    lands in block ``dst[i]`` at values ``[cols[i] * w, (cols[i] + 1) * w)``
+    of every layer's row and positions ``[cols[i] * p, (cols[i] + 1) * p)``
+    — one token (``w = Hkv * hd``, ``p = 1``) or, with ``cols`` None, a
+    whole block.  One dynamic update per entry keeps the pool in its held
+    layout: a scatter of windows narrower than a lane tile makes XLA lay
+    the pool out afresh.  Repeated ids keep the last write (idle slots'
+    trash writes).
+    """
+    if cols is None:
+        cols = jnp.zeros_like(dst)
+    wk, wv, p = k_rows.shape[-1], v_rows.shape[-1], pos_rows.shape[-1]
+
+    def body(i, c):
+        k, v, pos = c
+        k = jax.lax.dynamic_update_slice(
+            k, k_rows[:, i, None].astype(k.dtype), (0, dst[i], cols[i] * wk))
+        v = jax.lax.dynamic_update_slice(
+            v, v_rows[:, i, None].astype(v.dtype), (0, dst[i], cols[i] * wv))
+        pos = jax.lax.dynamic_update_slice(
+            pos, pos_rows[i, None].astype(pos.dtype), (dst[i], cols[i] * p))
+        return k, v, pos
+
+    k, v, pos = jax.lax.fori_loop(0, dst.shape[0], body,
+                                  (caches.k, caches.v, caches.pos))
+    return caches._replace(k=k, v=v, pos=pos)
 
 
 def paged_write_stacked(caches: PagedCache, k_news, v_news,
                         step) -> PagedCache:
-    """One scatter writes every slot's new token into its pool block.
+    """The tick's one deferred write: every slot's new token into its pool
+    block, every layer at once, in place in the held row layout.
 
     k_news/v_news: (L, B, 1, Hkv, hd); ``step`` scalar or (B,) per-slot
-    absolute positions.  A slot whose destination block is unallocated
-    (``table`` entry -1: an idle engine slot) writes to the trash block 0,
-    which no table references — the paged twin of the ring engine's
-    harmless inactive-row writes.
+    absolute positions.  Slot ``b``'s token lands in block ``dst`` of its
+    table at token ``off = step % bs``: values ``[off * Hkv * hd, (off + 1)
+    * Hkv * hd)`` of each layer's row.  A slot whose destination block is
+    unallocated (``table`` entry -1: an idle engine slot) writes to the
+    trash block 0, which no table references — the paged twin of the ring
+    engine's harmless inactive-row writes.
     """
-    bs = caches.k.shape[3]
+    bs = caches.pos.shape[1]
     B, nbs = caches.table.shape
     W = nbs * bs
     step = jnp.asarray(step)
@@ -313,24 +405,32 @@ def paged_write_stacked(caches: PagedCache, k_news, v_news,
     blk = jnp.take_along_axis(caches.table, jb[:, None], axis=1)[:, 0]
     dst = jnp.where(blk >= 0, blk, 0)
     off = jnp.mod(step, bs)
-    k_t = k_news[:, :, 0].transpose(1, 0, 2, 3)         # (B, L, Hkv, hd)
-    v_t = v_news[:, :, 0].transpose(1, 0, 2, 3)
-    k = caches.k.at[:, dst, :, off, :].set(k_t.astype(caches.k.dtype))
-    v = caches.v.at[:, dst, :, off, :].set(v_t.astype(caches.v.dtype))
-    pos = caches.pos.at[dst, off].set(step)
-    return caches._replace(k=k, v=v, pos=pos)
+    return paged_put_rows(caches, dst, paged_rows(k_news, 1)[:, :, 0],
+                          paged_rows(v_news, 1)[:, :, 0], step[:, None],
+                          cols=off)
 
 
 def decode_attention(q, cache, step, *, window: Optional[int],
                      q_per_kv: int = 1, k_new=None, v_new=None,
-                     scale: Optional[float] = None):
+                     scale: Optional[float] = None, per_head: bool = False):
     """Single-token attention against a ring cache (or paged view of one).
 
-    q: (B, 1, Hq, hd) roped; cache.k/v: (B, Hkv, W, hd); step: scalar int32
-    (absolute position of the query token) or (B,) per-example positions —
-    the batched serving engine decodes slots at different depths in one call.
-    A :class:`PagedLayerView` cache dispatches through
-    :func:`paged_gather_layer` first (bit-identical ring reconstruction).
+    q: (B, 1, Hq, hd) roped; cache.k/v: (B, Hkv, W, hd), or (B, W, Hkv *
+    hd) as :class:`KVRows`; step: scalar int32 (absolute position of the
+    query token) or (B,) per-example positions — the batched serving engine
+    decodes slots at different depths in one call.  A
+    :class:`PagedLayerView` cache is gathered first (:func:`paged_gather_rows`).
+
+    Every cache is read through one sequence of operations: as rows with
+    each token's heads side by side, ``(B, W, Hkv * hd)`` (the paged pool's
+    own order, as gathered), then per head with the ring axis minor, ``(B,
+    Hkv, hd, W)``, for the matmuls.  That is the layout the TPU holds a ring
+    cache in, so a ring cache is read as held and only a paged cache's
+    gathered transient is laid out (with ``hd`` minor, Delphi's 10-wide
+    heads would pad to 128 lanes); and one sequence keeps the paged decode
+    bit-identical to the ring decode it factors.  ``per_head`` reads a
+    ``(B, Hkv, W, hd)`` cache per head as it stands instead: cross-attention's
+    memory, which no paged cache mirrors (the TPU holds it ``hd`` minor).
 
     When ``k_new``/``v_new`` (B, 1, Hkv, hd) are given, the cache is treated
     as *read-only* and the new token is attended via an appended logit — the
@@ -341,17 +441,29 @@ def decode_attention(q, cache, step, *, window: Optional[int],
     read), and the result takes their width.
     """
     if isinstance(cache, PagedLayerView):
-        cache = paged_gather_layer(cache)
+        cache = paged_gather_rows(cache)
     B, _, Hq, hd = q.shape
-    Hkv, W = cache.k.shape[1], cache.k.shape[2]
+    W = cache.pos.shape[1]
     G = q_per_kv
+    Hkv = Hq // G
+    if per_head:
+        k, v = cache.k.swapaxes(2, 3), cache.v.swapaxes(2, 3)
+    else:
+        if not isinstance(cache, KVRows):    # (B, Hkv, W, d) -> side by side
+            cache = KVRows(*(a.transpose(0, 2, 1, 3).reshape(
+                B, W, Hkv * a.shape[-1]) for a in (cache.k, cache.v)),
+                cache.pos)
+        # (B, W, Hkv * d) -> (B, Hkv, d, W); explicit widths: a latent value
+        # plane may be zero-wide
+        k, v = (a.transpose(0, 2, 1).reshape(B, Hkv, a.shape[-1] // Hkv, W)
+                for a in (cache.k, cache.v))
     if scale is None:
         scale = hd ** -0.5
     step = jnp.asarray(step)
     if step.ndim == 1:
         step = step.reshape(B, 1, 1, 1)   # broadcast against pos (B,1,1,W)
     qg = q.reshape(B, Hkv, G, hd)
-    s = jnp.einsum("bhgd,bhwd->bhgw", qg, cache.k).astype(jnp.float32) * scale
+    s = jnp.einsum("bhgd,bhdw->bhgw", qg, k).astype(jnp.float32) * scale
     pos = cache.pos[:, None, None, :]
     valid = (pos >= 0) & (pos <= step)
     if k_new is not None:
@@ -370,14 +482,14 @@ def decode_attention(q, cache, step, *, window: Optional[int],
         m = jnp.maximum(m_c, s_new)
         p_c = jnp.exp(s - m[..., None])
         l = jnp.sum(p_c, axis=-1) + jnp.exp(s_new - m)
-        o = jnp.einsum("bhgw,bhwd->bhgd", p_c.astype(cache.v.dtype), cache.v)
+        o = jnp.einsum("bhgw,bhdw->bhgd", p_c.astype(v.dtype), v)
         o = o + (jnp.exp(s_new - m)[..., None].astype(v_new.dtype)
                  * v_new[:, 0][:, :, None, :])
         o = o / l[..., None].astype(o.dtype)
     else:
         p = jax.nn.softmax(s, axis=-1)
-        o = jnp.einsum("bhgw,bhwd->bhgd", p.astype(cache.v.dtype), cache.v)
-    return o.reshape(B, 1, Hq, cache.v.shape[-1])
+        o = jnp.einsum("bhgw,bhdw->bhgd", p.astype(v.dtype), v)
+    return o.reshape(B, 1, Hq, v.shape[2])
 
 
 # ---------------------------------------------------------------------------
@@ -414,8 +526,8 @@ def empty_paged_cache(cfg: ModelConfig, n_layers: int, num_blocks: int,
                          f"block_size {block_size}")
     h, dk, dv = cache_rows(cfg)
     return PagedCache(
-        k=jnp.zeros((n_layers, num_blocks, h, block_size, dk), dtype),
-        v=jnp.zeros((n_layers, num_blocks, h, block_size, dv), dtype),
+        k=jnp.zeros((n_layers, num_blocks, block_size * h * dk), dtype),
+        v=jnp.zeros((n_layers, num_blocks, block_size * h * dv), dtype),
         pos=jnp.full((num_blocks, block_size), -1, jnp.int32),
         table=jnp.full((slots, width // block_size), -1, jnp.int32),
     )
@@ -568,7 +680,7 @@ def attention(params, x, positions, cfg: ModelConfig, *, mode: str,
             if "bq" in params:
                 q = q + params["bq"].astype(dt)
             o = decode_attention(q, cache, jnp.int32(2**30), window=None,
-                                 q_per_kv=G)
+                                 q_per_kv=G, per_head=True)
             out = jnp.einsum("bshk,hkd->bsd", o, params["wo"].astype(dt))
             return out, cache
         q, k, v = _project_qkv(params, x, x, cfg)
@@ -708,16 +820,18 @@ def mla_attention(params, x, positions, cfg: ModelConfig, *, mode: str,
                                params["wkv_b"][:, :, :dn].astype(dt))
             q_lat = jnp.concatenate([q_lat, q_pe], axis=-1)
         if isinstance(cache, PagedLayerView):
-            cache = paged_gather_layer(cache)
+            cache = paged_gather_rows(cache)
         kw = {}
         if defer_write:
-            kw = dict(k_new=new[0], v_new=new[0][..., :r])
+            kw = dict(k_new=new[0], v_new=new[0])
         else:
             cache = cache_write(cache, *new, step)
+        # the rows serve as values too, whole: the attended latent is their
+        # first r columns (one layout of the rows for both matmuls)
         o = decode_attention(
-            q_lat, LayerCache(k=cache.k, v=cache.k[..., :r], pos=cache.pos),
-            step, window=win, q_per_kv=cfg.n_heads,
-            scale=(dn + cfg.qk_rope_head_dim) ** -0.5, **kw)
+            q_lat, cache._replace(v=cache.k), step, window=win,
+            q_per_kv=cfg.n_heads, scale=(dn + cfg.qk_rope_head_dim) ** -0.5,
+            **kw)[..., :r]
         with jax.named_scope("absorb"):
             o = jnp.einsum("bshr,rhv->bshv", o,
                            params["wkv_b"][:, :, dn:].astype(dt))

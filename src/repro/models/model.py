@@ -318,24 +318,6 @@ def _transformer_stack(layers, x, positions, cfg, *, mode, memory=None,
     # decode: caches are read-only inside the scan; new-token K/V are
     # collected and written with ONE stacked scatter afterwards (avoids
     # round-tripping the full cache through scan temporaries)
-    if isinstance(caches, attn_lib.PagedCache):
-        # the scan carries each layer's pool planes; the block ids and ring
-        # positions have no layer axis, so they are built once here and
-        # closed over.  decode_attention dispatches on the PagedLayerView.
-        if has_cross:
-            raise ValueError("paged KV cache does not support cross-"
-                             "attention stacks")
-        blocks, ring_pos = attn_lib.paged_ring_index(caches.pos, caches.table)
-        planes = (caches.k, caches.v)
-
-        def view(kv):
-            return attn_lib.PagedLayerView(kv[0], kv[1], blocks, ring_pos)
-    else:
-        planes = caches
-
-        def view(c):
-            return c
-
     def scan(x, stack, per_layer, read, xcs):
         def body(h, xs):
             lp, c, xc = xs
@@ -347,18 +329,38 @@ def _transformer_stack(layers, x, positions, cfg, *, mode, memory=None,
         x, (kv, aux) = jax.lax.scan(body, x, (stack, per_layer, xcs))
         return x, kv, jnp.sum(aux, axis=0)
 
-    if head is None:
-        x, kv, aux = scan(x, layers, planes, view, cross_caches)
+    paged = isinstance(caches, attn_lib.PagedCache)
+    if paged and has_cross:
+        raise ValueError("paged KV cache does not support cross-"
+                         "attention stacks")
+    if head is None and not paged:
+        x, kv, aux = scan(x, layers, caches, lambda c: c, cross_caches)
     else:
-        # the cache's layer axis spans both stacks: each layer reads its
-        # planes by index (slicing the pool per stack would copy it)
-        def at(i):
-            return view(jax.tree_util.tree_map(lambda a: a[i], planes))
-        nd = jax.tree_util.tree_leaves(head)[0].shape[0]
+        # each layer reads the cache by index: a head's layers come first
+        # on its layer axis, and the paged pool is gathered at (layer,
+        # block) from the whole held pool (slicing it per layer or per
+        # stack would copy it).  The pool's block ids and ring positions
+        # have no layer axis, so they are built once here.
+        if paged:
+            blocks, ring_pos = attn_lib.paged_ring_index(caches.pos,
+                                                         caches.table)
+            heads = attn_lib.cache_rows(cfg)[0]
+
+            def at(i):
+                return attn_lib.PagedLayerView(caches.k, caches.v, i, blocks,
+                                               ring_pos, heads)
+        else:
+            def at(i):
+                return jax.tree_util.tree_map(lambda a: a[i], caches)
         n = jax.tree_util.tree_leaves(layers)[0].shape[0]
-        x, kv_head, _ = scan(x, head, jnp.arange(nd), at, None)
-        x, kv, aux = scan(x, layers, jnp.arange(nd, nd + n), at, None)
-        kv = _cat_layers(kv_head, kv)
+        nd = 0
+        if head is not None:
+            nd = jax.tree_util.tree_leaves(head)[0].shape[0]
+            x, kv_head, _ = scan(x, head, jnp.arange(nd), at, None)
+        x, kv, aux = scan(x, layers, jnp.arange(nd, nd + n), at,
+                          cross_caches)
+        if head is not None:
+            kv = _cat_layers(kv_head, kv)
     caches = attn_lib.cache_write_stacked(caches, kv[0], kv[1], step)
     return x, caches, cross_caches, aux
 

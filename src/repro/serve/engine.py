@@ -48,6 +48,7 @@ from repro.configs import base as cb
 from repro.configs.base import ModelConfig
 from repro.core.sampler import advance_trajectory_state, sample_next_event
 from repro.kernels import tte_sample
+from repro.models import attention as attn_lib
 from repro.models import (decode_step, forward, forward_suffix,
                           make_decode_cache, make_paged_decode_cache,
                           mask_padded_positions)
@@ -411,14 +412,18 @@ def _fork_copy_rows_jit(cache, src, dst, max_pos):
 
 @functools.partial(jax.jit, donate_argnums=(0,))
 def _cow_block_jit(cache, src, dst):
-    """Copy-on-write: duplicate one pool block (k/v of every layer + its
-    position plane) before a slot's first write into a block it does not
-    exclusively own."""
+    """Copy-on-write: duplicate one pool block — its row in every layer of
+    the held ``(L, NB, bs * Hkv * hd)`` planes and its position row — in
+    place, before a slot's first write into a block it does not exclusively
+    own."""
     pc = cache["self"]
-    k = pc.k.at[:, dst].set(pc.k[:, src])
-    v = pc.v.at[:, dst].set(pc.v[:, src])
-    pos = pc.pos.at[dst].set(pc.pos[src])
-    return {"self": pc._replace(k=k, v=v, pos=pos)}
+
+    def row(a):             # block ``src`` of every layer: (L, 1, R)
+        return jax.lax.dynamic_slice_in_dim(a, src, 1, axis=1)
+
+    return {"self": attn_lib.paged_put_rows(pc, jnp.reshape(dst, (1,)),
+                                            row(pc.k), row(pc.v),
+                                            pc.pos[src][None])}
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
@@ -445,27 +450,25 @@ def _insert_blocks_jit(cache, rows_cache, dst, *, nblk):
     """Copy-on-admit: bucketed ring prefill rows -> paged pool blocks.
 
     ``rows_cache`` leaves are the prefill's ring rows (L, n, Hkv, W, hd);
-    the first ``nblk`` blocks of each row scatter to pool ids ``dst``
-    (n, nblk) in one jitted dispatch per admission batch.  Rows whose
-    prompt needs fewer blocks point their tail at the trash block 0 —
-    copied padding (pos = -1) that is never referenced by a table.
+    the first ``nblk`` blocks of each row become pool rows (token-major,
+    ``bs * Hkv * hd`` wide) at pool ids ``dst`` (n, nblk), written in place
+    in one jitted dispatch per admission batch.  Rows whose prompt needs
+    fewer blocks point their tail at the trash block 0 — copied padding
+    (pos = -1) that is never referenced by a table.
     """
     pc = cache["self"]
     rows = rows_cache["self"]
-    bs = pc.k.shape[3]
+    bs = pc.pos.shape[1]
     n = dst.shape[0]
-    d = dst.reshape(-1)
 
-    def blocks(a):          # (L, n, Hkv, W, hd) -> (L, n*nblk, Hkv, bs, hd)
-        L, _, Hkv, _, hd = a.shape
-        a = a[:, :, :, :nblk * bs, :].reshape(L, n, Hkv, nblk, bs, hd)
-        return a.transpose(0, 1, 3, 2, 4, 5).reshape(L, n * nblk, Hkv, bs, hd)
+    def blocks(a):          # (L, n, Hkv, W, hd) -> (L, n*nblk, bs*Hkv*hd)
+        r = attn_lib.paged_rows(a[:, :, :, :nblk * bs].swapaxes(2, 3), bs)
+        return r.reshape(r.shape[0], n * nblk, r.shape[-1])
 
-    k = pc.k.at[:, d].set(blocks(rows.k).astype(pc.k.dtype))
-    v = pc.v.at[:, d].set(blocks(rows.v).astype(pc.v.dtype))
     # ring pos is identical across layers: copy layer 0's view
-    pos = pc.pos.at[d].set(rows.pos[0, :, :nblk * bs].reshape(n * nblk, bs))
-    return {"self": pc._replace(k=k, v=v, pos=pos)}
+    pos = rows.pos[0, :, :nblk * bs].reshape(n * nblk, bs)
+    return {"self": attn_lib.paged_put_rows(
+        pc, dst.reshape(-1), blocks(rows.k), blocks(rows.v), pos)}
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
@@ -536,7 +539,7 @@ def _chunk_arrays(toks, ags, start: int, n: int, bs: int, table_row):
 def _suffix_chunk_jit(params, cache, tokens, ages, positions, ctx_ids, dst,
                       last_idx, *, cfg):
     """One chunked-prefill step: gather the slot's already-written context
-    blocks from the pool, run the suffix forward over the chunk, scatter the
+    blocks from the pool, run the suffix forward over the chunk, write the
     chunk's K/V (+ position planes) into its destination blocks.
 
     tokens/ages/positions: (B, Sc) bucketed chunk rows (pos -1 = padding);
@@ -546,14 +549,16 @@ def _suffix_chunk_jit(params, cache, tokens, ages, positions, ctx_ids, dst,
     logits at ``last_idx`` — the final chunk's bootstrap logits).
     """
     pc = cache["self"]
-    bs = pc.k.shape[3]
+    bs = pc.pos.shape[1]
     B, C = ctx_ids.shape
     safe = jnp.maximum(ctx_ids, 0)
+    heads = attn_lib.cache_rows(cfg)[0]
 
-    def gather(pool):       # (L, NB, Hkv, bs, hd) -> (L, B, C*bs, Hkv, hd)
-        g = pool[:, safe]   # (L, B, C, Hkv, bs, hd)
-        L, _, _, Hkv, _, hd = g.shape
-        return g.transpose(0, 1, 2, 4, 3, 5).reshape(L, B, C * bs, Hkv, hd)
+    def gather(pool):       # (L, NB, bs*Hkv*hd) -> (L, B, C*bs, Hkv, hd)
+        # rows by (layer, block), as the decode reads them: a gather of
+        # whole (L, 1, R) windows would lay the pool out block-major first
+        rows = pool[jnp.arange(pool.shape[0])[:, None, None], safe[None]]
+        return attn_lib.paged_tokens(rows, bs, heads)
 
     # the trash block's position plane receives REAL positions from idle
     # slots' discarded tick writes — mask padded context unconditionally
@@ -568,21 +573,19 @@ def _suffix_chunk_jit(params, cache, tokens, ages, positions, ctx_ids, dst,
                          moe_impl=_MOE_PREFILL)
     Sc = tokens.shape[1]
     nblk = Sc // bs
-    d = dst.reshape(-1)
 
-    def blocks(a):          # (L, B, Sc, Hkv, hd) -> (L, B*nblk, Hkv, bs, hd)
-        L, _, _, Hkv, hd = a.shape
-        a = a.reshape(L, B, nblk, bs, Hkv, hd).transpose(0, 1, 2, 4, 3, 5)
-        return a.reshape(L, B * nblk, Hkv, bs, hd)
+    def blocks(a):          # (L, B, Sc, Hkv, hd) -> (L, B*nblk, bs*Hkv*hd)
+        r = attn_lib.paged_rows(a, bs)
+        return r.reshape(r.shape[0], B * nblk, r.shape[-1])
 
-    k = pc.k.at[:, d].set(blocks(out["k"]).astype(pc.k.dtype))
-    v = pc.v.at[:, d].set(blocks(out["v"]).astype(pc.v.dtype))
     # full position planes land with the data: the padded tail of a partial
     # final block carries -1 (decode writes reclaim it a token at a time),
     # so stale positions from a block's previous owner can never leak
-    pos = pc.pos.at[d].set(positions.reshape(B * nblk, bs))
+    pc = attn_lib.paged_put_rows(pc, dst.reshape(-1), blocks(out["k"]),
+                                 blocks(out["v"]),
+                                 positions.reshape(B * nblk, bs))
     lg = out["logits"][:, 0].astype(jnp.float32)
-    return {"self": pc._replace(k=k, v=v, pos=pos)}, lg
+    return {"self": pc}, lg
 
 
 class BatchedEngine:

@@ -871,7 +871,7 @@ def test_mutation_sync_under_tick_is_exactly_one_rl006(tmp_path):
 def test_mutation_index_map_arity_is_exactly_one_rl007(tmp_path):
     res = _mutated_src(
         tmp_path, "kernels/paged_attention.py",
-        "lambda b, h, i, tbl, stp: (b, h, 0, 0)",
-        "lambda b, h, i, tbl: (b, h, 0, 0)")
+        "lambda b, i, tbl, stp: (b, 0, 0)",
+        "lambda b, i, tbl: (b, 0, 0)")
     assert [f.rule for f in res.new] == ["RL007"]
-    assert res.new[0].symbol == "kernels._paged_kernel.index-map-arity.4"
+    assert res.new[0].symbol == "kernels._paged_kernel.index-map-arity.3"

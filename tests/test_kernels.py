@@ -70,8 +70,9 @@ def _paged_inputs(key, B, Hkv, G, hd, bs, nbs, dtype, *, wrap=False):
         int(jax.random.randint(key, (), 0, 2**31 - 1)))
     NB = 1 + B * nbs
     W = nbs * bs
-    k_pool = jnp.asarray(rng.normal(size=(NB, Hkv, bs, hd))).astype(dtype)
-    v_pool = jnp.asarray(rng.normal(size=(NB, Hkv, bs, hd))).astype(dtype)
+    # one layer of the held pool: a token-major row per block
+    k_pool = jnp.asarray(rng.normal(size=(NB, bs * Hkv * hd))).astype(dtype)
+    v_pool = jnp.asarray(rng.normal(size=(NB, bs * Hkv * hd))).astype(dtype)
     q = jnp.asarray(rng.normal(size=(B, Hkv * G, hd))).astype(dtype)
     table = np.full((B, nbs), -1, np.int32)
     pos = np.full((NB, bs), -1, np.int32)

@@ -76,13 +76,13 @@ def test_absorbed_decode_equals_expanded_attention(paged):
     if paged:       # the same rows behind a block table, out of order
         nb = W // bs
         ids = np.arange(1, 2 * nb + 1)[::-1].reshape(2, nb)
-        k = jnp.zeros((2 * nb + 1, 1, bs, rw)).at[ids].set(
-            ring.k.reshape(2, 1, nb, bs, rw).transpose(0, 2, 1, 3, 4))
+        k = jnp.zeros((1, 2 * nb + 1, bs * rw)).at[0, ids].set(
+            ring.k.reshape(2, nb, bs * rw))          # one row a block
         ppos = jnp.full((2 * nb + 1, bs), -1, jnp.int32).at[ids].set(
             ring.pos.reshape(2, nb, bs))
         table = jnp.asarray(ids, jnp.int32)
         blocks, ring_pos = A.paged_ring_index(ppos, table)
-        cache = A.PagedLayerView(k, k[..., :0], blocks, ring_pos)
+        cache = A.PagedLayerView(k, k[..., :0], 0, blocks, ring_pos, heads=1)
     step = jnp.full((2,), S - 1, jnp.int32)
     out, new = A.mla_attention(p, x[:, S - 1:], None, cfg, mode="decode",
                                cache=cache, step=step, defer_write=True)
@@ -128,7 +128,8 @@ def test_paged_engine_matches_reference_forward(reference):
     ref, cfg, w, mcfg, params = reference
     eng = BatchedEngine(params, mcfg, slots=4, max_context=64,
                         temperature=0.0, cache="paged")
-    assert eng.cache["self"].k.shape[2:] == (1, 16, 40)     # one row a token
+    assert eng.cache["self"].k.shape[2:] == (16 * 40,)    # one row a token
+    assert eng.cache["self"].v.shape[2:] == (0,)
     rng = np.random.default_rng(0)
     reqs = [Request(tokens=rng.integers(3, 512, n).astype(np.int32),
                     max_new=6) for n in (5, 17, 33)]
